@@ -71,6 +71,8 @@ def main(argv=None) -> None:
                          "every bench cell's mode (repeatable) -- compare "
                          "mixed layouts against the pure-mode tables")
     args = ap.parse_args(argv)
+    from repro.launch.cli import init_compile_cache
+    init_compile_cache()
     mode_overrides = ()
     if args.mode_override:
         from repro.core.strategy import parse_mode_override

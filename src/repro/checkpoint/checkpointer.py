@@ -34,7 +34,6 @@ import ml_dtypes
 import numpy as np
 from jax.sharding import NamedSharding
 
-from repro.compat import flatten_with_path
 
 # numpy cannot round-trip ml_dtypes (bf16 etc.) through np.save; store the
 # raw bits and record the logical dtype in the manifest.
@@ -111,7 +110,7 @@ class Checkpointer:
         arbitrary JSON-serializable dict recorded in the manifest (the
         restart driver stores the mesh signature and whether a
         cross-step carry section rides along)."""
-        path_leaves, treedef = flatten_with_path(tree)
+        path_leaves, treedef = jax.tree.flatten_with_path(tree)
         # snapshot to host memory first (cheap, lets async write proceed
         # while the next step runs; also decouples the write from any
         # donation of the live buffers by the next compiled step)
@@ -198,7 +197,7 @@ class Checkpointer:
                     if l.get("section") in sections]
         else:
             idxs = list(range(n_saved))
-        ex_path_leaves, ex_treedef = flatten_with_path(example_tree)
+        ex_path_leaves, ex_treedef = jax.tree.flatten_with_path(example_tree)
         ex_paths = [_keystr(kp) for kp, _ in ex_path_leaves]
         if version >= 2:
             saved_paths = [saved_leaves[i]["path"] for i in idxs]

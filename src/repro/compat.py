@@ -1,200 +1,11 @@
-"""JAX version compatibility layer.
+"""The one JAX symbol this repo takes from a private module.
 
-The repo targets the current JAX API surface (``jax.shard_map`` with
-``check_vma``, varying-mesh-axis typing, ``all_gather_invariant``); this
-module makes it run unchanged on JAX 0.4.x (0.4.37 is the pinned CI
-toolchain). Every versioned import in ``src/`` routes through here:
-
-  shard_map            jax.shard_map | jax.experimental.shard_map, and the
-                       check_vma -> check_rep kwarg rename
-  all_gather_invariant falls back to jax.lax.all_gather (the invariant
-                       gather exists only on VMA-typed JAX; the varying
-                       gather is numerically identical, it just loses the
-                       replication-typing guarantee)
-  pvary / typeof       no-ops on pre-VMA JAX (avals carry no vma there,
-                       so there is nothing to lift)
-  flatten_with_path    jax.tree.flatten_with_path | jax.tree_util
-  make_mesh            drops the axis_types kwarg where unsupported
-
-Feature flags (HAS_VMA, HAS_INVARIANT_GATHER) let callers branch when the
-semantic difference matters (it never changes numerics, only typing
-strictness and comm-accounting op names).
+The repo targets JAX 0.9: call sites use ``jax.shard_map``,
+``jax.typeof``, ``jax.lax.axis_size`` and ``jax.lax.pcast`` directly.
+The invariant (replicated-typed) all-gather is not exported publicly in
+0.9, so it is imported here, once, from ``jax._src``. Its output is
+typed replicated over the gathered axis, which the frozen-parameter
+gathers and the hier strategy's widened updated-shard gather need to
+satisfy their shard_map out_specs.
 """
-from __future__ import annotations
-
-import inspect
-from typing import Any, Callable, Optional, Sequence, Tuple
-
-import jax
-
-# ---------------------------------------------------------------------------
-# shard_map: location + check_vma/check_rep rename
-# ---------------------------------------------------------------------------
-
-if hasattr(jax, "shard_map"):
-    _shard_map = jax.shard_map
-else:  # JAX <= 0.4.x
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-_SHARD_MAP_PARAMS = set(inspect.signature(_shard_map).parameters)
-HAS_VMA = "check_vma" in _SHARD_MAP_PARAMS
-
-if not HAS_VMA:
-    # check_rep=True is load-bearing on pre-VMA shard_map: its rewrite
-    # pass is what inserts the pbroadcast/psum pairs that make gradients
-    # of replicated-in-storage params (MiCS pod-replication, small
-    # replicated tensors) correct. The stock 0.4.x registry just lacks a
-    # rule for the `name` primitive our remat-policy cache boundaries
-    # rely on (checkpoint_name) -- name is a unary pass-through, so the
-    # standard rep-preserving rule is exact. setdefault semantics: a
-    # future jax that ships its own rule wins.
-    try:
-        from jax.experimental import shard_map as _shmap_mod
-        from jax._src.ad_checkpoint import name_p as _name_p
-        _shmap_mod.register_standard_check(_name_p)
-        _shmap_mod.register_standard_rewrite(_name_p)
-    except Exception:  # pragma: no cover - registry moved/renamed
-        pass
-
-
-def shard_map(f, mesh=None, in_specs=None, out_specs=None,
-              check_vma: bool = True, **kw):
-    """``jax.shard_map`` with the ``check_vma``/``check_rep`` rename
-    papered over. Call with the new-style kwarg; on old JAX the value is
-    forwarded as ``check_rep`` (with the `name` rule patched in above)."""
-    if HAS_VMA:
-        return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs, check_vma=check_vma, **kw)
-    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      check_rep=check_vma, **kw)
-
-
-# ---------------------------------------------------------------------------
-# Invariant all-gather
-# ---------------------------------------------------------------------------
-
-try:
-    from jax._src.lax.parallel import all_gather_invariant as _agi
-    HAS_INVARIANT_GATHER = True
-except ImportError:  # pre-VMA JAX: the varying gather is the only gather
-    _agi = None
-    HAS_INVARIANT_GATHER = False
-
-# Pre-VMA replication typing for the invariant gather. 0.4.x shard_map
-# registers all_gather as a "standard collective" (varying -> varying),
-# so an all-gather can never DISCHARGE a replication obligation -- e.g.
-# the hier strategy's post-update pod-axis gather of optimizer shards
-# back to the pod-replicated param layout fails the out_specs rep check
-# even though the gathered value is replicated by construction. The real
-# invariant gather types this correctly on VMA JAX; here we recover it
-# with a no-op pass-through primitive whose check/rewrite rules add the
-# gathered axes to the replication set (semantically exact: every member
-# of the gathered axis holds the identical concatenated result).
-_rep_assert_p = None
-if not HAS_VMA and _agi is None:
-    try:
-        from jax.experimental import shard_map as _shmap_mod2
-        from jax.interpreters import ad as _ad, mlir as _mlir
-
-        _rep_assert_p = jax.core.Primitive("rep_assert")
-        _rep_assert_p.def_impl(lambda x, *, axes: x)
-        _rep_assert_p.def_abstract_eval(lambda x, *, axes: x)
-        _mlir.register_lowering(
-            _rep_assert_p, lambda ctx, x, *, axes: [x])
-        _ad.deflinear2(_rep_assert_p, lambda ct, x, *, axes: (ct,))
-
-        @_shmap_mod2.register_check(_rep_assert_p)
-        def _rep_assert_check(mesh, x_rep, *, axes):
-            return x_rep | set(axes) if x_rep is not None else x_rep
-
-        @_shmap_mod2.register_rewrite(_rep_assert_p)
-        def _rep_assert_rewrite(mesh, in_reps, x, *, axes):
-            (x_rep,) = in_reps
-            out_rep = x_rep | set(axes) if x_rep is not None else x_rep
-            return [_rep_assert_p.bind(x, axes=axes)], [out_rep]
-    except Exception:  # pragma: no cover - registry moved/renamed
-        _rep_assert_p = None
-
-
-def all_gather_invariant(x, axis_name, *, axis: int = 0, tiled: bool = False):
-    """Invariant (replicated-typed) all-gather, or the plain all-gather on
-    JAX versions without it (typed replicated via the rep_assert shim
-    when the 0.4.x registries are available). One axis name per call
-    (matching the real invariant gather's signature)."""
-    if _agi is not None:
-        return _agi(x, axis_name, axis=axis, tiled=tiled)
-    y = jax.lax.all_gather(x, axis_name, axis=axis, tiled=tiled)
-    if _rep_assert_p is not None:
-        axes = (axis_name,) if isinstance(axis_name, str) \
-            else tuple(axis_name)
-        y = _rep_assert_p.bind(y, axes=axes)
-    return y
-
-
-# ---------------------------------------------------------------------------
-# VMA typing helpers
-# ---------------------------------------------------------------------------
-
-def typeof(x):
-    """jax.typeof, falling back to the abstract value on older JAX (whose
-    avals carry no ``vma`` attribute -- callers getattr with a default)."""
-    if hasattr(jax, "typeof"):
-        return jax.typeof(x)
-    return jax.core.get_aval(x)
-
-
-def axis_size(axis_name) -> int:
-    """Static size of a named mesh axis from inside shard_map.
-    jax.lax.axis_size where it exists; the axis-env frame on older JAX
-    (which returns either a frame object or the size itself)."""
-    if hasattr(jax.lax, "axis_size"):
-        return jax.lax.axis_size(axis_name)
-    frame = jax.core.axis_frame(axis_name)
-    return getattr(frame, "size", frame)
-
-
-def pvary(x, axis_names: Tuple[str, ...]):
-    """Lift a value to vary over ``axis_names``. On pre-VMA JAX values
-    carry no varying type, so this is the identity."""
-    if not axis_names:
-        return x
-    if hasattr(jax.lax, "pvary"):
-        return jax.lax.pvary(x, axis_names)
-    return x
-
-
-# ---------------------------------------------------------------------------
-# Pytree path flattening
-# ---------------------------------------------------------------------------
-
-def flatten_with_path(tree, is_leaf: Optional[Callable] = None):
-    if hasattr(jax.tree, "flatten_with_path"):
-        return jax.tree.flatten_with_path(tree, is_leaf=is_leaf)
-    return jax.tree_util.tree_flatten_with_path(tree, is_leaf=is_leaf)
-
-
-# ---------------------------------------------------------------------------
-# Mesh construction
-# ---------------------------------------------------------------------------
-
-_MAKE_MESH_PARAMS = set(inspect.signature(jax.make_mesh).parameters)
-
-
-def make_mesh(shape: Sequence[int], axes: Sequence[str], devices=None):
-    """jax.make_mesh with Auto axis types where the kwarg exists; older
-    JAX has no axis-type concept (everything is Auto). ``devices``
-    restricts the mesh to an explicit device subset (elastic remesh over
-    the survivors); without it jax fills the mesh from all visible
-    devices."""
-    shape, axes = tuple(shape), tuple(axes)
-    kw = {}
-    if devices is not None:
-        if "devices" in _MAKE_MESH_PARAMS:
-            kw["devices"] = tuple(devices)
-        else:  # pragma: no cover - very old jax: build the Mesh directly
-            import numpy as _np
-            return jax.sharding.Mesh(
-                _np.asarray(devices, dtype=object).reshape(shape), axes)
-    if "axis_types" in _MAKE_MESH_PARAMS and hasattr(jax.sharding, "AxisType"):
-        kw["axis_types"] = (jax.sharding.AxisType.Auto,) * len(axes)
-    return jax.make_mesh(shape, axes, **kw)
+from jax._src.lax.parallel import all_gather_invariant  # noqa: F401

@@ -9,7 +9,7 @@ both hops in int8 (symmetric per-256-block scales) halves the bytes at
 ~0.4% relative error per tensor.
 
 Forward-only compression: the backward of this psum is the standard
-identity/pvary transpose (exact), so gradients see no additional
+identity/pcast transpose (exact), so gradients see no additional
 quantization beyond what the forward activations already carry.
 
 The quantize/dequantize/accumulate hot loops are the shared codepath in
@@ -23,7 +23,7 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
-from repro.compat import all_gather_invariant, axis_size, pvary
+from repro.compat import all_gather_invariant
 from repro.core.grad_compress import _impl_kw
 from repro.kernels import ops as kops
 from repro.kernels.quant import BLOCK
@@ -34,7 +34,7 @@ def _int8_allreduce(x: jax.Array, axis_name: str,
     """Quantized ring all-reduce: int8 RS (via all_to_all + local
     dequant-accumulate) followed by int8 invariant AG. Returns the
     (approximately) summed tensor, invarying over `axis_name`."""
-    n = axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     shape, dtype = x.shape, x.dtype
     flat = x.reshape(-1).astype(jnp.float32)
     total = flat.shape[0]
@@ -76,7 +76,7 @@ def _fwd(x, axis_name, impl):
 
 
 def _bwd(axis_name, impl, _, g):
-    return (pvary(g, (axis_name,)),)
+    return (jax.lax.pcast(g, (axis_name,), to="varying"),)
 
 
 int8_psum.defvjp(_fwd, _bwd)
@@ -90,7 +90,7 @@ def int8_bwd_psum(x, axis_name: str, impl: str = "jnp"):
     transpose inserts a full all-reduce on its cotangent (the Megatron
     g-bar). Wrapping the input here compresses that implicit reduction
     the same way int8_psum compresses the forward one."""
-    return pvary(x, (axis_name,))
+    return jax.lax.pcast(x, (axis_name,), to="varying")
 
 
 def _bp_fwd(x, axis_name, impl):

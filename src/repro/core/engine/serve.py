@@ -14,17 +14,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import HAS_VMA, shard_map
 from repro.configs.base import ShapeCell
-
-# Serve steps are gradient-free pure forwards: replication checking is a
-# purely static verification there (the rep rewrite has no numerical
-# role without AD). The pre-VMA checker cannot prove the decode-state
-# outputs (e.g. rwkv token-shift xprev) are model-replicated even though
-# they are, so keep the check on VMA-typed JAX and drop it on the
-# legacy checker.
-_SERVE_CHECK = HAS_VMA
-
 
 def serve_batch_dims(bundle, cell: ShapeCell,
                      seq_sharded: bool = False) -> Tuple[int, P]:
@@ -84,9 +74,8 @@ def build_prefill_step(bundle):
         in_specs = (bundle.leaf_specs, bspec, bspec, st_specs)
     else:
         in_specs = (bundle.leaf_specs, bspec, st_specs)
-    fn = shard_map(body, mesh=mesh, in_specs=in_specs,
-                   out_specs=(logits_spec, st_specs),
-                   check_vma=_SERVE_CHECK)
+    fn = jax.shard_map(body, mesh=mesh, in_specs=in_specs,
+                       out_specs=(logits_spec, st_specs))
     return jax.jit(fn, donate_argnums=(2,) if cfg.num_encoder_layers == 0
                    else (3,))
 
@@ -104,10 +93,9 @@ def build_decode_step(bundle, seq_sharded: bool = False):
 
     st_specs = state_specs(bundle, cell, seq_sharded)
     logits_spec = P(bspec[0] if len(bspec) else None, "model")
-    fn = shard_map(body, mesh=mesh,
-                   in_specs=(bundle.leaf_specs, bspec, st_specs),
-                   out_specs=(logits_spec, st_specs),
-                   check_vma=_SERVE_CHECK)
+    fn = jax.shard_map(body, mesh=mesh,
+                       in_specs=(bundle.leaf_specs, bspec, st_specs),
+                       out_specs=(logits_spec, st_specs))
     return jax.jit(fn, donate_argnums=(2,))
 
 
@@ -126,9 +114,8 @@ def state_specs(bundle, cell: ShapeCell, seq_sharded: bool):
 
 
 def _specs_for_state(bundle, example, batch_axes, seq_sharded: bool):
-    from repro.compat import flatten_with_path
     mi = bundle.mi
-    paths, treedef = flatten_with_path(example)
+    paths, treedef = jax.tree.flatten_with_path(example)
     specs = []
     for path, arr in paths:
         keys = [str(getattr(k, "key", getattr(k, "idx", k)))
@@ -239,11 +226,10 @@ def build_paged_decode_step(bundle, kv):
 
     st_specs = paged_state_specs(bundle, cell, kv)
     logits_spec = P(bspec[0] if len(bspec) else None, "model")
-    fn = shard_map(body, mesh=mesh,
-                   in_specs=(bundle.leaf_specs, bspec, bspec, bspec,
-                             st_specs),
-                   out_specs=(logits_spec, st_specs),
-                   check_vma=_SERVE_CHECK)
+    fn = jax.shard_map(body, mesh=mesh,
+                       in_specs=(bundle.leaf_specs, bspec, bspec, bspec,
+                                 st_specs),
+                       out_specs=(logits_spec, st_specs))
     return jax.jit(fn, donate_argnums=(4,))
 
 
@@ -265,11 +251,10 @@ def build_prefill_chunk_step(bundle, kv):
 
     st_specs = paged_state_specs(bundle, cell, kv)
     logits_spec = P(bspec[0] if len(bspec) else None, "model")
-    fn = shard_map(body, mesh=mesh,
-                   in_specs=(bundle.leaf_specs, bspec, bspec, bspec,
-                             bspec, st_specs),
-                   out_specs=(logits_spec, st_specs),
-                   check_vma=_SERVE_CHECK)
+    fn = jax.shard_map(body, mesh=mesh,
+                       in_specs=(bundle.leaf_specs, bspec, bspec, bspec,
+                                 bspec, st_specs),
+                       out_specs=(logits_spec, st_specs))
     return jax.jit(fn, donate_argnums=(5,))
 
 
@@ -300,6 +285,6 @@ def build_greedy_pick(bundle):
 
     logits_spec = P(bspec[0] if len(bspec) else None, "model")
     out_spec = P(bspec[0] if len(bspec) else None)
-    fn = shard_map(body, mesh=mesh, in_specs=(logits_spec,),
-                   out_specs=out_spec, check_vma=_SERVE_CHECK)
+    fn = jax.shard_map(body, mesh=mesh, in_specs=(logits_spec,),
+                       out_specs=out_spec)
     return jax.jit(fn)

@@ -7,9 +7,7 @@ Everything here is PER LEAF, so per-tensor mixed sharding
 (CompositeStrategy) needs no special casing: the opt-widening
 reduce-scatter/all-gather pair fires for exactly the leaves whose opt
 spec is wider than their storage spec (hier embeddings, ZeRO-2-for-
-experts), the pre-VMA gradient psums cover exactly the leaves stored
-replicated over some axes (mics/hier groups, frozen layouts), and the
-async reduce stream defers exactly the leaves with a non-empty stage 1
+experts), and the async reduce stream defers exactly the leaves with a non-empty stage 1
 (the streaming groups) -- single-stage groups' reduces pass through
 untouched.
 
@@ -49,7 +47,7 @@ Three gradient/optimizer schedules exist on the accumulation path:
   ``carry`` holds step i's accumulated storage-level grads plus the last
   microbatch's stage-1-level pending grads (the stream-2 fold,
   generalized to the step level). ``piped`` finalizes the carry at its
-  TOP -- pod reduce + grad_sync + widen reduce-scatter + clip + AdamW +
+  TOP -- pod reduce + widen reduce-scatter + clip + AdamW +
   widened all-gather -- and runs its own microbatch loop against the
   UPDATED parameters, so the schedule is staleness-free: the epilogue
   collectives merely sit next to step i+1's first-microbatch forward
@@ -59,7 +57,8 @@ Three gradient/optimizer schedules exist on the accumulation path:
   one epilogue, every piped step retires exactly one while deferring its
   own, flush retires the last. Carry leaves cross the jit boundary with
   a leading 'partial' dimension sharded over every mesh axis their
-  payload spec does not mention, so the pre-reduction partial sums are
+  payload still varies over but does not mention (the pod axis of the
+  pending stage-1 grads), so the pre-reduction partial sums are
   honestly typed (each device row holds its own partial; per-chip bytes
   are one shard -- core/schedule.py:cross_step_buffer_bytes is the
   analytic cost).
@@ -73,8 +72,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import (HAS_VMA, all_gather_invariant, pvary, shard_map,
-                          typeof)
+from repro.compat import all_gather_invariant
 from repro.core import schedule as sched
 from repro.core.strategy import spec_axes
 from repro.optim.adamw import adamw_update, clip_by_global_norm
@@ -109,15 +107,19 @@ def _stage1_storage_spec(spec: P, pdef, plan) -> P:
     return P(*entries)
 
 
-def _carried_spec(base: P, pdef, mi):
+def _carried_spec(base: P, varying, pdef, mi):
     """(full_spec, global_shape) of one carry leaf: the payload spec
-    plus a leading 'partial' dim sharded over every mesh axis the
-    payload does not mention. Pre-reduction gradients genuinely differ
-    along those axes (partial sums awaiting their psum), so the leading
-    dim makes the global array honest -- each device row holds its own
-    partial -- while per-chip storage stays one shard."""
-    names = tuple(mi.axis_names)
-    lead = tuple(a for a in names if a not in spec_axes(base))
+    plus a leading 'partial' dim sharded over the mesh axes the payload
+    varies over (``varying``, the storage spec's axes: autodiff types a
+    gradient like its parameter) but does not mention. Pre-reduction
+    gradients genuinely differ along those axes (partial sums awaiting
+    their reduce), so the leading dim makes the global array honest --
+    each device row holds its own partial -- while per-chip storage
+    stays one shard. Over every other axis the gradient is already
+    reduced, and the carry is typed replicated there, so the finalized
+    parameters and optimizer state satisfy their out_specs."""
+    lead = tuple(a for a in mi.axis_names
+                 if a in varying and a not in spec_axes(base))
     entries = list(base) + [None] * (len(pdef.shape) - len(base))
     full = P(lead if len(lead) > 1 else (lead[0] if lead else None),
              *entries)
@@ -138,7 +140,7 @@ def cross_step_carry_layout(bundle):
         spec = bundle.leaf_specs[i]
         for key, base in (("g_acc", spec),
                           ("pending", _stage1_storage_spec(spec, d, plan))):
-            full, shape = _carried_spec(base, d, bundle.mi)
+            full, shape = _carried_spec(base, spec_axes(spec), d, bundle.mi)
             out[key].append((full, shape, d.dtype))
     return out
 
@@ -148,9 +150,9 @@ def cross_step_carry_signature(bundle):
     checkpoint flatten order (the ``carry`` dict's keys sort g_acc before
     pending) -- what ``runtime/elastic.reshard_state`` compares against a
     saved manifest's carry section to decide mesh-compatibility. The
-    leading partial dim is mesh-shaped (the product of the unmentioned
-    axes' sizes), so a mesh change shows up here even when the payload
-    shapes agree; a carry that fails this check must be invalidated and
+    leading partial dim is mesh-shaped (the product of the varying,
+    unmentioned axes' sizes), so a mesh change can show up here even
+    when the payload shapes agree; a carry that fails this check must be invalidated and
     re-primed, never ``device_put`` as stale partials."""
     layout = cross_step_carry_layout(bundle)
     return [(tuple(shape), str(jnp.dtype(dtype)))
@@ -159,12 +161,11 @@ def cross_step_carry_signature(bundle):
 
 
 def _lift(x, axes):
-    """pvary ``x`` over whichever of ``axes`` its vma is missing (no-op
-    on pre-VMA JAX): carry outputs must vary over every axis their out
-    spec mentions."""
-    have = set(getattr(typeof(x), "vma", ()) or ())
-    need = tuple(a for a in axes if a not in have)
-    return pvary(x, need) if need else x
+    """Cast ``x`` to varying over whichever of ``axes`` its vma is
+    missing: carry outputs must vary over every axis their out spec
+    mentions."""
+    need = tuple(a for a in axes if a not in jax.typeof(x).vma)
+    return jax.lax.pcast(x, need, to="varying") if need else x
 
 
 # ---------------------------------------------------------------------------
@@ -199,26 +200,6 @@ def _build_parts(bundle):
             if a not in _entry_axes(bundle.leaf_specs[i], d.fsdp_dim))
         if extra:
             widen[j] = (d.fsdp_dim, extra)
-
-    # Pre-VMA JAX: shard_map's AD does not auto-insert the cross-axis
-    # reductions for grads of params stored REPLICATED over some mesh
-    # axes (pod-replicated MiCS/hier/frozen layouts, model-replicated
-    # kv/norm weights, min_shard_size-replicated tensors) -- each device
-    # would keep only its local partial. Current JAX's varying-mesh-axis
-    # type system inserts these psums automatically (transpose of the
-    # implicit pvary), so the explicit sum is gated on HAS_VMA. The
-    # gather transposes already reduce over the axes present in the
-    # storage spec; widened leaves' sum over the widening axes is
-    # handled by the rs_widen reduce-scatter instead.
-    grad_sync = {}
-    if not HAS_VMA:
-        for j, i in enumerate(bundle.train_idx):
-            waxes = widen.get(j, (None, ()))[1]
-            missing = tuple(a for a in mi.axis_names
-                            if a not in spec_axes(bundle.leaf_specs[i])
-                            and a not in waxes)
-            if missing:
-                grad_sync[j] = missing
 
     def rs_widen(g, dim, axes):
         return jax.lax.psum_scatter(g, axes, scatter_dimension=dim,
@@ -335,9 +316,6 @@ def _build_parts(bundle):
         updated-shard all-gather. One call site per schedule so the op
         order (and therefore the bits) are identical whether the
         epilogue runs fused or carried across the step boundary."""
-        if grad_sync:
-            grads = [jax.lax.psum(g, grad_sync[j]) if j in grad_sync else g
-                     for j, g in enumerate(grads)]
         if widen:
             grads = [rs_widen(g, *widen[j]) if j in widen else g
                      for j, g in enumerate(grads)]
@@ -350,11 +328,9 @@ def _build_parts(bundle):
                           for j, p_ in enumerate(new_params)]
         return new_params, new_opt, gnorm
 
-    # derive the loss-carry zero from a replicated input rather than a
-    # literal: scan requires the carry's replication type to match the
-    # body output's (which is replicated over every axis after the loss
-    # psums), and a bare constant carries no replication type on
-    # pre-VMA JAX
+    # the loss-carry zero: scan requires the carry's replication type to
+    # match the body output's (replicated over every axis after the
+    # loss psums)
     def ce_zero(opt_state):
         return (opt_state["step"] * 0).astype(jnp.float32)
 
@@ -438,11 +414,10 @@ def _build_fused(bundle, c):
                    "tokens": cnt}
         return new_params, new_opt, metrics
 
-    fn = shard_map(
+    fn = jax.shard_map(
         step_body, mesh=c.mesh,
         in_specs=(c.train_specs, c.frozen_specs, c.opt_specs, c.bspecs),
-        out_specs=(c.train_specs, c.opt_specs, c.metric_specs),
-        check_vma=True)
+        out_specs=(c.train_specs, c.opt_specs, c.metric_specs))
     return jax.jit(fn, donate_argnums=(0, 2))
 
 
@@ -465,12 +440,11 @@ def _build_piped(bundle, c):
                    "grad_norm": gnorm, "tokens": jnp.float32(1)}
         return new_params, new_opt, pack(g_acc2, pending2), metrics
 
-    fn = shard_map(
+    fn = jax.shard_map(
         step_body, mesh=c.mesh,
         in_specs=(c.train_specs, c.frozen_specs, c.opt_specs, carry_specs,
                   c.bspecs),
-        out_specs=(c.train_specs, c.opt_specs, carry_specs, c.metric_specs),
-        check_vma=True)
+        out_specs=(c.train_specs, c.opt_specs, carry_specs, c.metric_specs))
     return jax.jit(fn, donate_argnums=(0, 2, 3))
 
 
@@ -493,11 +467,10 @@ def build_train_prime(bundle):
                    "grad_norm": jnp.float32(0), "tokens": jnp.float32(1)}
         return pack(g_acc, pending), metrics
 
-    fn = shard_map(
+    fn = jax.shard_map(
         step_body, mesh=c.mesh,
         in_specs=(c.train_specs, c.frozen_specs, c.opt_specs, c.bspecs),
-        out_specs=(carry_specs, c.metric_specs),
-        check_vma=True)
+        out_specs=(carry_specs, c.metric_specs))
     return jax.jit(fn)
 
 
@@ -518,9 +491,8 @@ def build_train_flush(bundle):
             c.fold(g_acc, pending), opt_state)
         return new_params, new_opt, {"grad_norm": gnorm}
 
-    fn = shard_map(
+    fn = jax.shard_map(
         step_body, mesh=c.mesh,
         in_specs=(c.train_specs, c.opt_specs, carry_specs),
-        out_specs=(c.train_specs, c.opt_specs, {"grad_norm": P()}),
-        check_vma=True)
+        out_specs=(c.train_specs, c.opt_specs, {"grad_norm": P()}))
     return jax.jit(fn, donate_argnums=(0, 1, 2))

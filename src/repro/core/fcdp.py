@@ -37,20 +37,17 @@ from __future__ import annotations
 from typing import Optional
 
 import jax
-from jax.ad_checkpoint import checkpoint_name
+# the remat policy matches the primitive checkpoint_name binds, which
+# JAX does not export; no fallback: a policy that saved nothing would
+# quietly turn fcdp into a full-remat zero3
+from jax._src.ad_checkpoint import name_p
+from jax.ad_checkpoint import (Offloadable, Recompute, Saveable,
+                               checkpoint_name)
 
 from repro.compat import all_gather_invariant
 from repro.core.partition import ParamDef
 from repro.core.residency import residency_of
 from repro.core.strategy import GatherPlan, resolve_strategy
-
-try:  # name-based remat policies need the `name` primitive
-    from jax._src.ad_checkpoint import name_p
-    import jax._src.interpreters.partial_eval as pe
-    _HAVE_POLICY_INTERNALS = True
-except Exception:  # pragma: no cover - future jax versions
-    name_p, pe = None, None
-    _HAVE_POLICY_INTERNALS = False
 
 CACHE_NAME = "fcdp_cache"
 FULL_NAME = "fcdp_full"
@@ -219,9 +216,6 @@ def make_remat_policy(cache_placement: str, activation_policy: str = "save_all",
         device and leaves regather/device groups untouched, so the
         per-segment promotion is safe on mixed-strategy bodies.
     """
-    if not _HAVE_POLICY_INTERNALS:  # pragma: no cover
-        return jax.checkpoint_policies.nothing_saveable
-
     # torch-autograd-like 'save_all': keep the outputs of matmuls and of
     # paid-for collectives; recompute cheap elementwise chains (incl. the
     # f32 norm upcasts, which would otherwise dominate activation memory).
@@ -236,11 +230,11 @@ def make_remat_policy(cache_placement: str, activation_policy: str = "save_all",
     COLLECTIVE_SAVE_PRIMS = {"psum", "psum2", "psum_invariant",
                              "all_to_all", "psum_scatter"}
 
-    def policy(prim, *_, **params):
+    def policy(prim, *avals, **params):
         s = getattr(prim, "name", str(prim))
         if s == "all_gather" or s == "all_gather_invariant":
             # gathered tensors are never implicitly saved: the whole point
-            return pe.Recompute
+            return Recompute
         if prim is name_p:
             name = params.get("name")
             if name == CACHE_NAME or (name or "").startswith(CACHE_NAME + ":"):
@@ -249,25 +243,30 @@ def make_remat_policy(cache_placement: str, activation_policy: str = "save_all",
                 if promote_to_device and placement == "host":
                     placement = "device"
                 if placement == "device":
-                    return pe.Saveable
+                    return Saveable
                 if placement == "host":
-                    if host_offload:
-                        return pe.Offloadable(src="device", dst="pinned_host")
-                    return pe.Saveable
-                return pe.Recompute
+                    # the host tier holds matrices. A vector (norm scale,
+                    # bias) stays in HBM: its cache is a rounding error,
+                    # and stacking its one-row slices into a host buffer
+                    # across the layer scan is a DMA the TPU compiler
+                    # refuses (sublane-misaligned update)
+                    if host_offload and avals[0].ndim >= 2:
+                        return Offloadable(src="device", dst="pinned_host")
+                    return Saveable
+                return Recompute
             if name == FULL_NAME:
-                return pe.Recompute
+                return Recompute
             if name == ACT_NAME:
                 if activation_policy == "offload_acts":
-                    return pe.Offloadable(src="device", dst="pinned_host")
-                return pe.Saveable
-            return pe.Recompute
+                    return Offloadable(src="device", dst="pinned_host")
+                return Saveable
+            return Recompute
         if activation_policy == "save_all" and s in SAVE_PRIMS:
-            return pe.Saveable
+            return Saveable
         if (activation_policy == "save_collectives"
                 and s in COLLECTIVE_SAVE_PRIMS):
-            return pe.Saveable
-        return pe.Recompute
+            return Saveable
+        return Recompute
 
     return policy
 

@@ -26,7 +26,6 @@ from typing import Tuple
 import jax
 import jax.numpy as jnp
 
-from repro.compat import axis_size
 from repro.kernels import ops as kops
 from repro.kernels.quant import BLOCK, SCALE_EPS  # noqa: F401  (re-export)
 
@@ -58,7 +57,7 @@ def int8_psum_scatter(g: jax.Array, axis_name: str, dim: int,
     dequant-accumulate inner loop. Result: the local shard of the
     reduced tensor.
     """
-    n = axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     if n == 1:
         return g
     # move dim to front and split into n chunks
@@ -108,7 +107,7 @@ compressed_stage1_gather.defvjp(_fwd, _bwd)
 def _quantized_gather_fwd(w, axis_name: str, dim: int, impl: str):
     """int8-transported stage-1 all-gather: quantize the local shard,
     gather blocks + scales over the pod axis, dequantize on arrival."""
-    n = axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     w_moved = jnp.moveaxis(w, dim, 0)
     elems = w_moved.size
     q, s = _quantize(w_moved, impl)

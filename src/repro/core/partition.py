@@ -30,7 +30,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro.compat import flatten_with_path
 from repro.core.strategy import resolve_strategy
 
 
@@ -88,7 +87,7 @@ def tree_map_defs(fn: Callable, tree, *rest):
 
 def label_tree(tree):
     """Attach dotted-path labels to every ParamDef in the tree."""
-    paths_vals, treedef = flatten_with_path(tree, is_leaf=is_def)
+    paths_vals, treedef = jax.tree.flatten_with_path(tree, is_leaf=is_def)
     out = []
     for path, pdef in paths_vals:
         name = ".".join(str(getattr(k, "key", getattr(k, "idx", k))) for k in path)

@@ -46,7 +46,6 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.compat import axis_size
 
 BLOCK_M = 128
 BLOCK_N = 128
@@ -62,7 +61,11 @@ def _pad_dim(x, mult: int, axis: int):
 
 
 def _matmul_kernel(x_ref, w_ref, o_ref):
-    o_ref[...] = jnp.dot(x_ref[...], w_ref[...])
+    # the TPU's matrix unit accumulates in f32 (Mosaic rejects a bf16
+    # accumulator); round once to the output dtype
+    o_ref[...] = jnp.dot(x_ref[...], w_ref[...],
+                         preferred_element_type=jnp.float32
+                         ).astype(o_ref.dtype)
 
 
 def matmul_chunk(x, w, block_m: int = BLOCK_M, block_n: int = BLOCK_N,
@@ -121,7 +124,7 @@ def ring_ag_matmul(x, w_shard, axis_name: str, *, impl: str = "jnp",
     chunk's matmul so the transfer and the compute are concurrently
     ready in program order (XLA overlaps them); chunk results land in
     disjoint column slices of the output."""
-    n = axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     Nc = w_shard.shape[1]
     if n == 1:
         return _chunk_mm(x, w_shard, impl, block_m, block_n, interpret)
@@ -153,7 +156,7 @@ def ring_matmul_rs(a, b, axis_name: str, *, impl: str = "jnp",
     around the ring (ranks j+2, ..., j-1, finally j), so each hop's
     transfer overlaps the receiver's partial matmul. The accumulation
     order is fixed by that schedule; kernels/ref.py mirrors it."""
-    n = axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     N = b.shape[1]
     assert N % n == 0, (b.shape, n)
     Nc = N // n
@@ -207,7 +210,7 @@ def _fused_bwd(axis_name, mode, impl, block_m, block_n, interpret, res, g):
     # mode='both': ring-fused backward. dx accumulates per-chunk
     # contributions in ring order (re-associated); dw is the fused
     # matmul->reduce-scatter dual.
-    n = axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     K = x.shape[-1]
     Nc = w_shard.shape[1]
     x2 = x.reshape(-1, K)

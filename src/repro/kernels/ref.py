@@ -98,9 +98,10 @@ def int8_dequant_acc_ref(q, s):
 
 def matmul_chunk_ref(x, w, block_m: int = 128, block_n: int = 128):
     """Tile-loop mirror of collective_matmul.matmul_chunk: pad to the
-    (block_m, block_n) grid, one jnp.dot per tile with the contraction
-    kept whole, slice the pad back off. Interpret-mode Pallas executes
-    exactly this per-tile dot, so comparisons can be bit-exact."""
+    (block_m, block_n) grid, one f32-accumulated jnp.dot per tile with
+    the contraction kept whole, rounded once to the output dtype, slice
+    the pad back off. Interpret-mode Pallas executes exactly this
+    per-tile dot, so comparisons can be bit-exact."""
     M, K = x.shape
     N = w.shape[1]
     pm, pn = (-M) % block_m, (-N) % block_n
@@ -110,7 +111,9 @@ def matmul_chunk_ref(x, w, block_m: int = 128, block_n: int = 128):
     rows = []
     for i in range(xp.shape[0] // block_m):
         tiles = [jnp.dot(xp[i * block_m:(i + 1) * block_m],
-                         wp[:, j * block_n:(j + 1) * block_n])
+                         wp[:, j * block_n:(j + 1) * block_n],
+                         preferred_element_type=jnp.float32
+                         ).astype(out_dtype)
                  for j in range(wp.shape[1] // block_n)]
         rows.append(jnp.concatenate(tiles, axis=1))
     return jnp.concatenate(rows, axis=0)[:M, :N].astype(out_dtype)

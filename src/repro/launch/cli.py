@@ -19,6 +19,8 @@ release; pass ``prefetch_depth`` instead.
 from __future__ import annotations
 
 import argparse
+import os
+from pathlib import Path
 
 from repro.configs.base import ACTIVATION_POLICIES, SystemConfig
 from repro.core.strategy import (DEFAULT_STRATEGY, parse_mode_override,
@@ -110,6 +112,29 @@ def add_system_args(parser: argparse.ArgumentParser, *,
                    help="codepath for the per-chunk matmul inside the "
                         "fused ring")
     return g
+
+
+def add_mesh_args(parser: argparse.ArgumentParser) -> None:
+    """Mesh sizes of a run on the devices that are present (without
+    --smoke): pod x data x model must equal the device count."""
+    for axis in ("pod", "data", "model"):
+        parser.add_argument(f"--{axis}", type=int, default=1,
+                            help=f"mesh {axis} size (without --smoke)")
+
+
+def init_compile_cache() -> None:
+    """Keep JAX's persistent compilation cache at one fixed path.
+
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX reads it and nothing
+    is set here. Otherwise the cache lives in ``.jax_cache`` at the root
+    of the checkout: the path is part of what a later run looks up, so
+    it never names a temp dir, a pid or a time. Called from each
+    launcher's ``main()``, never at import."""
+    if os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        return
+    import jax
+    root = Path(__file__).resolve().parents[3]
+    jax.config.update("jax_compilation_cache_dir", str(root / ".jax_cache"))
 
 
 def system_config_from_args(args: argparse.Namespace,

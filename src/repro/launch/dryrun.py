@@ -25,7 +25,8 @@ from repro.configs.base import (RunConfig, SystemConfig, shape_cell,
 from repro.configs.registry import (ARCH_IDS, cell_supported, get_config)
 from repro.core.engine import StepBundle
 from repro.core.strategy import DEFAULT_STRATEGY
-from repro.launch.cli import add_system_args, system_config_from_args
+from repro.launch.cli import (add_system_args, init_compile_cache,
+                              system_config_from_args)
 from repro.launch.mesh import make_production_mesh
 from repro.launch.roofline import (collect_collectives, flops_bytes_from_jaxpr,
                                    fused_overlap_credit,
@@ -226,6 +227,7 @@ def main():
         ap.error("--cross-step-pipeline requires --async-grad-reduce "
                  "and --microbatch >= 2")
 
+    init_compile_cache()
     RESULTS_DIR.mkdir(exist_ok=True)
     results = []
     if args.all:

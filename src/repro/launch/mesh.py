@@ -15,13 +15,11 @@ from typing import Optional, Sequence, Tuple
 
 import jax
 
-from repro.compat import make_mesh as _compat_make_mesh
-
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return _compat_make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_mesh(shape: Sequence[int], axes: Sequence[str], devices=None):
@@ -29,7 +27,25 @@ def make_mesh(shape: Sequence[int], axes: Sequence[str], devices=None):
     ``devices`` restricts the mesh to an explicit subset -- the elastic
     path passes the surviving devices so a shrunk mesh never spans chips
     the surviving shape does not cover."""
-    return _compat_make_mesh(shape, axes, devices=devices)
+    kw = {} if devices is None else {"devices": tuple(devices)}
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes),
+                         **kw)
+
+
+def make_device_mesh(pod: int = 1, data: int = 1, model: int = 1):
+    """Mesh over exactly the visible devices (one chip, one host of
+    four): pod x data x model must equal the device count. A pod axis
+    of 1 is left out, as on a single-pod production mesh; a 2-wide pod
+    axis on one host runs over ICI, not DCN."""
+    n = len(jax.devices())
+    if pod * data * model != n:
+        raise ValueError(
+            f"mesh pod={pod} x data={data} x model={model} = "
+            f"{pod * data * model} devices, but {n} are visible")
+    if pod > 1:
+        return make_mesh((pod, data, model), ("pod", "data", "model"))
+    return make_mesh((data, model), ("data", "model"))
 
 
 def make_smoke_mesh(n_devices: Optional[int] = None,

@@ -26,8 +26,9 @@ from repro.core.engine import StepBundle
 from repro.core.engine.serve import default_paged_kv
 from repro.core.kv_cache import PagedKVConfig
 from repro.core.serve_schedule import PagedServeEngine, Request, summarize
-from repro.launch.cli import add_system_args, system_config_from_args
-from repro.launch.mesh import make_production_mesh, make_smoke_mesh
+from repro.launch.cli import (add_mesh_args, add_system_args,
+                              init_compile_cache, system_config_from_args)
+from repro.launch.mesh import make_device_mesh, make_smoke_mesh
 
 
 def mixed_requests(n: int, seq_len: int, gen_len: int, vocab: int,
@@ -49,7 +50,7 @@ def main(argv=None):
     ap.add_argument("--arch", required=True, choices=ARCH_IDS)
     add_system_args(ap)
     ap.add_argument("--smoke", action="store_true")
-    ap.add_argument("--multi-pod", action="store_true")
+    add_mesh_args(ap)
     ap.add_argument("--batch", type=int, default=8)
     ap.add_argument("--seq-len", type=int, default=128,
                     help="max prompt+generation length per request")
@@ -64,12 +65,13 @@ def main(argv=None):
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
 
+    init_compile_cache()
     if args.smoke:
         cfg = get_smoke_config(args.arch)
         mesh = make_smoke_mesh()
     else:
         cfg = get_config(args.arch)
-        mesh = make_production_mesh(multi_pod=args.multi_pod)
+        mesh = make_device_mesh(args.pod, args.data, args.model)
     cell = ShapeCell("serve", "decode", args.seq_len, args.batch)
     run = RunConfig(model=cfg, shape=cell,
                     system=system_config_from_args(args, min_shard_size=8))

@@ -2,15 +2,22 @@
 
   PYTHONPATH=src python -m repro.launch.train --arch qwen2.5-3b --smoke \
       --steps 50 --mode fcdp
+  PYTHONPATH=src python -m repro.launch.train --arch qwen2.5-3b \
+      --peft --batch 1 --seq-len 4096 --steps 4      # one chip
 
 --smoke runs the reduced config of the same family on the local CPU
-devices; the full configs target the production meshes (dry-run them
-with repro.launch.dryrun). Includes checkpoint/restart, heartbeat,
-straggler monitoring, and optional failure injection (--fail-at).
+devices. Without it the published config runs at full width on the
+devices that are present: the mesh is --pod x --data x --model, whose
+product must equal the device count (the 256/512-chip production meshes
+are compiled by repro.launch.dryrun). --layers cuts the depth; widths
+never change. Includes checkpoint/restart, heartbeat, straggler
+monitoring, and optional failure injection (--fail-at). An empty
+--ckpt-dir runs without checkpoints, so a failing step is fatal.
 """
 from __future__ import annotations
 
 import argparse
+import dataclasses
 import functools
 import time
 from pathlib import Path
@@ -24,8 +31,9 @@ from repro.configs.base import (OptimizerConfig, RunConfig, ShapeCell,
 from repro.configs.registry import ARCH_IDS, get_config, get_smoke_config
 from repro.core.engine import StepBundle
 from repro.data.pipeline import DataConfig, ShardedLoader, SyntheticPackedLM
-from repro.launch.cli import add_system_args, system_config_from_args
-from repro.launch.mesh import make_production_mesh, make_smoke_mesh
+from repro.launch.cli import (add_mesh_args, add_system_args,
+                              init_compile_cache, system_config_from_args)
+from repro.launch.mesh import make_device_mesh, make_smoke_mesh
 from repro.optim.adamw import init_opt_state
 from repro.runtime.elastic import mesh_meta, reshard_state
 from repro.runtime.fault_tolerance import (FailureInjector, HeartbeatMonitor,
@@ -43,8 +51,15 @@ def build(args):
                          args.batch or 8)
     else:
         cfg = get_config(args.arch)
-        mesh = make_production_mesh(multi_pod=args.multi_pod)
-        cell = shape_cell(args.cell)
+        mesh = make_device_mesh(args.pod, args.data, args.model)
+        base = shape_cell(args.cell)
+        cell = ShapeCell(base.name, "train", args.seq_len or base.seq_len,
+                         args.batch or base.global_batch)
+    if args.layers:
+        if not 0 < args.layers <= cfg.num_layers:
+            raise ValueError(f"--layers {args.layers}: {cfg.name} has "
+                             f"{cfg.num_layers} layers")
+        cfg = dataclasses.replace(cfg, num_layers=args.layers)
     sysc = system_config_from_args(
         args, min_shard_size=8 if args.smoke else 2048)
     run = RunConfig(model=cfg, shape=cell, system=sysc,
@@ -73,14 +88,19 @@ class RunState:
             self.flush_fn = self.bundle.make_train_flush()
         params = self.bundle.init_all_params(seed=run.seed)
         self.train_p, self.frozen_p = self.bundle.split(params)
+        # placed like the step's own opt outputs: left to the compiler,
+        # the zero moments come out replicated (a full copy per chip)
+        # and the step compiles a second time for the next step's layout
         self.opt = jax.jit(functools.partial(
-            init_opt_state, sys=run.system))(self.train_p)
+            init_opt_state, sys=run.system),
+            out_shardings=self.bundle.state_shardings()["opt"])(self.train_p)
         ds = SyntheticPackedLM(run.model, run.shape, DataConfig(run.seed))
         enc_dim = run.model.d_model if run.model.num_encoder_layers else 0
         self.loader = ShardedLoader(ds, mesh,
                                     self.bundle.batch_spec(run.shape),
                                     enc_embed_dim=enc_dim)
         self.metrics_log = []
+        self.result = None       # run_with_restarts' summary, once run
 
     def do_train_step(self, batch):
         """One training step under whichever schedule is live. With the
@@ -140,30 +160,50 @@ def main(argv=None):
     ap.add_argument("--cell", default="train_4k")
     add_system_args(ap)
     ap.add_argument("--smoke", action="store_true")
-    ap.add_argument("--multi-pod", action="store_true")
+    ap.add_argument("--multi-pod", action="store_true",
+                    help="with --smoke: carve a 2-wide pod axis")
+    add_mesh_args(ap)
+    ap.add_argument("--layers", type=int, default=0,
+                    help="depth cut: number of layers (0 = the config's)")
     ap.add_argument("--steps", type=int, default=20)
     ap.add_argument("--batch", type=int, default=0)
     ap.add_argument("--seq-len", type=int, default=0)
     ap.add_argument("--lr", type=float, default=3e-4)
     ap.add_argument("--microbatch", type=int, default=0)
-    ap.add_argument("--ckpt-dir", default="/tmp/repro_ckpt")
+    ap.add_argument("--ckpt-dir", default="",
+                    help="checkpoint directory ('' = no checkpoints: a "
+                         "fixed default would resume a stale run)")
     ap.add_argument("--ckpt-every", type=int, default=10)
     ap.add_argument("--fail-at", type=int, nargs="*", default=[])
     args = ap.parse_args(argv)
 
+    init_compile_cache()
     st = build(args)
-    ckpt = Checkpointer(args.ckpt_dir)
+    cfg, cell = st.run.model, st.run.shape
+    dev = jax.devices()[0]
+    print(f"arch {cfg.name}: d_model {cfg.d_model} d_ff {cfg.d_ff} "
+          f"heads {cfg.num_heads}/{cfg.num_kv_heads} vocab {cfg.vocab_size} "
+          f"layers {cfg.num_layers}"
+          + ("" if args.smoke else
+             f" (of {get_config(args.arch).num_layers})")
+          + f" | batch {cell.global_batch} x seq {cell.seq_len} | mesh "
+          f"{dict(st.mesh.shape)} | mode {st.run.system.mode}"
+          f"{' peft' if st.run.system.peft else ''} | "
+          f"{dev.platform} {dev.device_kind} x{len(jax.devices())}")
+    ckpt = Checkpointer(args.ckpt_dir) if args.ckpt_dir else None
     injector = FailureInjector(fail_at_steps=tuple(args.fail_at))
     monitor = StragglerMonitor()
     hb = HeartbeatMonitor(timeout_s=600).start()
 
     def do_step(step: int):
+        t0 = time.perf_counter()
         injector.maybe_fail(step)
         batch = st.loader.get(step)
         m = st.do_train_step(batch)
-        loss = float(m["loss"])
+        loss = float(m["loss"])          # waits for the step to finish
         row = {"step": step, "loss": loss,
-               "grad_norm": float(m["grad_norm"])}
+               "grad_norm": float(m["grad_norm"]),
+               "step_s": time.perf_counter() - t0}
         if st.last_primed:
             # pipeline-fill step: no norm yet (the next piped step
             # reports this step's, the flush reports the last one)
@@ -171,9 +211,12 @@ def main(argv=None):
         st.metrics_log.append(row)
         if step % max(args.steps // 20, 1) == 0:
             print(f"step {step:5d} loss {loss:.4f} "
-                  f"gnorm {float(m['grad_norm']):.3f}")
+                  f"gnorm {float(m['grad_norm']):.3f} "
+                  f"time {row['step_s']:.3f}s")
 
     def save(step: int):
+        if ckpt is None:
+            return
         # the checkpoint is taken mid-pipeline: the cross-step carry is
         # persisted as a manifest-v2 carry section (not flushed), so a
         # restart resumes the piped schedule bit-identically to an
@@ -183,6 +226,8 @@ def main(argv=None):
                   meta=mesh_meta(st.mesh))
 
     def restore() -> int:
+        if ckpt is None:     # only the initial call: without
+            return 0         # checkpoints no failure is retried
         # a crash can land while an async save is still writing: drain
         # it first, or latest_step() would miss the in-flight checkpoint
         # and silently resume a full interval earlier
@@ -218,18 +263,21 @@ def main(argv=None):
     # persist the initial state before the first step: a failure inside
     # the first checkpoint interval then restores to a well-defined step
     # 0 instead of replaying onto partially-trained live state
-    if ckpt.latest_step() is None:
+    if ckpt is not None and ckpt.latest_step() is None:
         ckpt.save(0, st.state_tree(), blocking=True,
                   meta=mesh_meta(st.mesh))
 
     t0 = time.time()
     result = run_with_restarts(
         args.steps, do_step, save, restore,
-        checkpoint_every=args.ckpt_every, monitor=monitor, heartbeat=hb,
-        flush_fn=st.flush_carry)
+        checkpoint_every=args.ckpt_every,
+        max_restarts=3 if ckpt is not None else 0,
+        monitor=monitor, heartbeat=hb, flush_fn=st.flush_carry)
     st.flush_carry()
     hb.stop()
-    ckpt.wait()
+    if ckpt is not None:
+        ckpt.wait()
+    st.result = result
     dt = time.time() - t0
     toks = args.steps * st.run.shape.global_batch * st.run.shape.seq_len
     final_loss = next(m["loss"] for m in reversed(st.metrics_log)

@@ -9,7 +9,6 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from repro.compat import pvary, typeof
 from repro.launch.mesh import fsdp_axes_of
 
 
@@ -78,8 +77,7 @@ def tp_region_in(x, mi: MeshInfo):
     act_psum='int8' the implicit backward all-reduce on this tensor's
     cotangent runs in int8 (Megatron g-bar compression)."""
     if mi.act_psum == "int8" and mi.tp > 1:
-        vma = set(getattr(typeof(x), "vma", ()) or ())
-        if "model" not in vma:
+        if "model" not in jax.typeof(x).vma:
             from repro.core.act_compress import int8_bwd_psum
             return int8_bwd_psum(x, "model", mi.quant_impl)
     return x
@@ -111,12 +109,10 @@ def pvary_like(x, ref):
 
     Zero-initialized scan carries are invarying constants, while scan
     bodies produce device-varying values; under shard_map's VMA typing
-    the carry init must be pvary'd to the body's type. No-op outside
+    the carry init must be cast to the body's type. No-op outside
     shard_map (avals then carry no vma)."""
-    want = set(getattr(typeof(ref), "vma", ()) or ())
-    have = set(getattr(typeof(x), "vma", ()) or ())
-    missing = tuple(want - have)
-    return pvary(x, missing) if missing else x
+    missing = tuple(jax.typeof(ref).vma - jax.typeof(x).vma)
+    return jax.lax.pcast(x, missing, to="varying") if missing else x
 
 
 def pvary_tree_like(tree, ref_tree):

@@ -13,7 +13,6 @@ from typing import Any, Dict, List, Optional, Sequence
 import jax
 import jax.numpy as jnp
 
-from repro.compat import pvary, typeof
 from repro.configs.base import OptimizerConfig, SystemConfig
 
 
@@ -58,10 +57,10 @@ def clip_by_global_norm(grads: List[jax.Array], rep_factors: Sequence[float],
     if axes:
         # lift to varying over every axis (identical copies psum-corrected
         # by the replication factors above), then reduce over all
-        have = set(getattr(typeof(local), "vma", ()) or ())
+        have = jax.typeof(local).vma
         missing = tuple(a for a in axes if a not in have)
         if missing:
-            local = pvary(local, missing)
+            local = jax.lax.pcast(local, missing, to="varying")
         total = jax.lax.psum(local, axes)
     else:
         total = local

@@ -10,8 +10,10 @@ are gone for good.
 """
 from __future__ import annotations
 
+import sys
 import threading
 import time
+import traceback
 from collections import deque
 from dataclasses import dataclass, field
 from typing import Any, Callable, Deque, Dict, List, Optional
@@ -161,6 +163,9 @@ def run_with_restarts(train_steps: int, step_fn: Callable[[int], Any],
             total_restarts += 1
             if restarts > max_restarts:
                 raise
+            traceback.print_exc()
+            print(f"step {step} failed; restart {total_restarts}",
+                  file=sys.stderr)
             if flush_fn is not None:
                 try:
                     flush_fn()

@@ -1,7 +1,9 @@
-"""Shared test fixtures. Device count is raised to 8 for the mesh tests
-(NOT 512 -- the production meshes are exercised only via the dry-run)."""
+"""Shared test fixtures. The suite runs on the CPU backend, so it never
+takes a chip, with the device count raised to 8 for the mesh tests (NOT
+512 -- the production meshes are exercised only via the dry-run)."""
 import os
 
+os.environ.setdefault("JAX_PLATFORMS", "cpu")
 os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=8")
 
 import jax  # noqa: E402

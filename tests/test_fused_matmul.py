@@ -18,7 +18,6 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.compat import shard_map
 from repro.kernels import collective_matmul as cm
 from repro.kernels import ref
 
@@ -63,9 +62,9 @@ def test_matmul_chunk_block_shapes(rng):
 def _ring_ag(mesh, axis, x, w, **kw):
     """ring_ag_matmul with x replicated and w column-sharded over axis."""
     fn = lambda x_, w_: cm.ring_ag_matmul(x_, w_, axis, **kw)
-    return jax.jit(shard_map(fn, mesh=mesh,
-                             in_specs=(P(), P(None, axis)),
-                             out_specs=P(), check_vma=False))(x, w)
+    return jax.jit(jax.shard_map(fn, mesh=mesh,
+                                 in_specs=(P(), P(None, axis)),
+                                 out_specs=P(), check_vma=False))(x, w)
 
 
 @pytest.mark.parametrize("axis,n", [("data", 4), ("model", 2)])
@@ -76,9 +75,9 @@ def test_ring_ag_matmul_vs_unfused(mesh2, rng, axis, n):
     w = jnp.asarray(rng.normal(0, 1, (24, 8 * n)), jnp.float32)
     base = lambda x_, w_: x_ @ jax.lax.all_gather(w_, axis, axis=1,
                                                   tiled=True)
-    want = jax.jit(shard_map(base, mesh=mesh2,
-                             in_specs=(P(), P(None, axis)),
-                             out_specs=P(), check_vma=False))(x, w)
+    want = jax.jit(jax.shard_map(base, mesh=mesh2,
+                                 in_specs=(P(), P(None, axis)),
+                                 out_specs=P(), check_vma=False))(x, w)
     got = _ring_ag(mesh2, axis, x, w)
     assert jnp.array_equal(got, want)
 
@@ -115,9 +114,9 @@ def test_ring_ag_matmul_batched_x(mesh2, rng):
     w = jnp.asarray(rng.normal(0, 1, (16, 8 * 4)), jnp.float32)
     base = lambda x_, w_: x_ @ jax.lax.all_gather(w_, "data", axis=1,
                                                   tiled=True)
-    want = jax.jit(shard_map(base, mesh=mesh2,
-                             in_specs=(P(), P(None, "data")),
-                             out_specs=P(), check_vma=False))(x, w)
+    want = jax.jit(jax.shard_map(base, mesh=mesh2,
+                                 in_specs=(P(), P(None, "data")),
+                                 out_specs=P(), check_vma=False))(x, w)
     assert jnp.array_equal(_ring_ag(mesh2, "data", x, w), want)
 
 
@@ -131,9 +130,9 @@ def test_ring_matmul_rs_vs_ref(mesh2, rng):
     def body(a_, b_):
         out = cm.ring_matmul_rs(a_[0], b_[0], "data")
         return out[None]
-    got = jax.jit(shard_map(body, mesh=mesh2,
-                            in_specs=(P("data"), P("data")),
-                            out_specs=P("data"), check_vma=False))(a, b)
+    got = jax.jit(jax.shard_map(body, mesh=mesh2,
+                                in_specs=(P("data"), P("data")),
+                                out_specs=P("data"), check_vma=False))(a, b)
     for r in range(n):
         assert jnp.array_equal(got[r], ref.matmul_rs_ref(a, b, r)), r
 
@@ -148,15 +147,15 @@ def test_ring_matmul_rs_sums_to_psum_scatter(mesh2, rng):
     def base(a_, b_):
         return jax.lax.psum_scatter(a_[0] @ b_[0], "data",
                                     scatter_dimension=1, tiled=True)[None]
-    want = jax.jit(shard_map(base, mesh=mesh2,
-                             in_specs=(P("data"), P("data")),
-                             out_specs=P("data"), check_vma=False))(a, b)
+    want = jax.jit(jax.shard_map(base, mesh=mesh2,
+                                 in_specs=(P("data"), P("data")),
+                                 out_specs=P("data"), check_vma=False))(a, b)
 
     def body(a_, b_):
         return cm.ring_matmul_rs(a_[0], b_[0], "data")[None]
-    got = jax.jit(shard_map(body, mesh=mesh2,
-                            in_specs=(P("data"), P("data")),
-                            out_specs=P("data"), check_vma=False))(a, b)
+    got = jax.jit(jax.shard_map(body, mesh=mesh2,
+                                in_specs=(P("data"), P("data")),
+                                out_specs=P("data"), check_vma=False))(a, b)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=1e-5, atol=1e-5)
 
@@ -170,10 +169,10 @@ def _grads(mesh, axis, x, w, mode):
         y = cm.fused_matmul(x_, w_, axis, mode)
         return jnp.sum(y * y)
     fn = jax.grad(loss, argnums=(0, 1))
-    return jax.jit(shard_map(fn, mesh=mesh,
-                             in_specs=(P(), P(None, axis)),
-                             out_specs=(P(), P(None, axis)),
-                             check_vma=False))(x, w)
+    return jax.jit(jax.shard_map(fn, mesh=mesh,
+                                 in_specs=(P(), P(None, axis)),
+                                 out_specs=(P(), P(None, axis)),
+                                 check_vma=False))(x, w)
 
 
 def test_ag_matmul_grad_bit_parity(mesh2, rng):
@@ -186,11 +185,11 @@ def test_ag_matmul_grad_bit_parity(mesh2, rng):
     def base_loss(x_, w_):
         y = x_ @ jax.lax.all_gather(w_, "data", axis=1, tiled=True)
         return jnp.sum(y * y)
-    want = jax.jit(shard_map(jax.grad(base_loss, argnums=(0, 1)),
-                             mesh=mesh2,
-                             in_specs=(P(), P(None, "data")),
-                             out_specs=(P(), P(None, "data")),
-                             check_vma=False))(x, w)
+    want = jax.jit(jax.shard_map(jax.grad(base_loss, argnums=(0, 1)),
+                                 mesh=mesh2,
+                                 in_specs=(P(), P(None, "data")),
+                                 out_specs=(P(), P(None, "data")),
+                                 check_vma=False))(x, w)
     got = _grads(mesh2, "data", x, w, "ag_matmul")
     assert jnp.array_equal(got[0], want[0])
     assert jnp.array_equal(got[1], want[1])
@@ -212,7 +211,7 @@ def test_both_grad_vs_ring_oracles(mesh2, rng):
     def per_rank(x_, w_):
         dx, dw = jax.grad(loss, argnums=(0, 1))(x_, w_)
         return dx[None], dw
-    dx_all, dw = jax.jit(shard_map(
+    dx_all, dw = jax.jit(jax.shard_map(
         per_rank, mesh=mesh2, in_specs=(P(), P(None, "data")),
         out_specs=(P("data"), P(None, "data")), check_vma=False))(x, w)
 
@@ -230,10 +229,10 @@ def test_both_grad_vs_ring_oracles(mesh2, rng):
     # and the unfused gradient is the same sum in a different order
     base = lambda x_, w_: jnp.sum(
         (x_ @ jax.lax.all_gather(w_, "data", axis=1, tiled=True)) ** 2)
-    want = jax.jit(shard_map(jax.grad(base, argnums=(0, 1)), mesh=mesh2,
-                             in_specs=(P(), P(None, "data")),
-                             out_specs=(P(), P(None, "data")),
-                             check_vma=False))(x, w)
+    want = jax.jit(jax.shard_map(jax.grad(base, argnums=(0, 1)), mesh=mesh2,
+                                 in_specs=(P(), P(None, "data")),
+                                 out_specs=(P(), P(None, "data")),
+                                 check_vma=False))(x, w)
     np.testing.assert_allclose(np.asarray(dx_all[0]), np.asarray(want[0]),
                                rtol=1e-5, atol=1e-5)
 

@@ -242,16 +242,15 @@ def test_async_reduce_composes_with_int8(mesh3, rng):
 def test_quantized_gather_shard_map_impl_agreement(mesh3, rng):
     """quantized_stage1_gather under shard_map: the pallas_interpret
     kernel path must match the jnp path bit-for-bit (same quant grid)."""
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
     from repro.core.grad_compress import quantized_stage1_gather
     w = jnp.asarray(rng.normal(0, 1, (16, 64)), jnp.float32)
 
     def run(impl):
-        f = shard_map(
+        f = jax.shard_map(
             lambda x: quantized_stage1_gather(x, "pod", 0, False, impl),
             mesh=mesh3, in_specs=P("pod"), out_specs=P(),
-            check_rep=False)    # all_gather output is VMA-varying
+            check_vma=False)    # all_gather output is VMA-varying
         return np.asarray(jax.jit(f)(w))
 
     out_jnp = run("jnp")

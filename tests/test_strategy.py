@@ -332,3 +332,22 @@ def test_prefetch_roofline_overlap_visibility():
             < rep_on["collective_s"])
     # overlap is capped by the compute term
     assert rep_on["prefetch"]["overlapped_s"] <= rep_on["compute_s"] + 1e-12
+
+
+def test_remat_policy_host_tier_holds_matrices():
+    """A host-placed cache offloads matrices to pinned host memory and
+    keeps vectors (norm scales, biases) in HBM: the TPU compiler refuses
+    the one-row host updates a stacked vector cache would need."""
+    from jax.ad_checkpoint import Offloadable, Recompute, Saveable
+    from jax._src.ad_checkpoint import name_p
+
+    from repro.core.fcdp import make_remat_policy
+    pol = make_remat_policy("host")
+    mat = jax.core.ShapedArray((2048, 1024), jnp.bfloat16)
+    vec = jax.core.ShapedArray((2048,), jnp.bfloat16)
+    assert isinstance(pol(name_p, mat, name="fcdp_cache:host"), Offloadable)
+    assert pol(name_p, vec, name="fcdp_cache:host") is Saveable
+    assert pol(name_p, mat, name="fcdp_cache:device") is Saveable
+    assert pol(name_p, mat, name="fcdp_cache:regather") is Recompute
+    assert make_remat_policy("host", host_offload=False)(
+        name_p, mat, name="fcdp_cache:host") is Saveable

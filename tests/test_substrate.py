@@ -220,7 +220,6 @@ def test_int8_activation_allreduce_training_quality(mesh3):
 
 def test_int8_allreduce_unit(mesh3, rng):
     """int8_psum matches exact psum within blockwise-quant error."""
-    from repro.compat import shard_map
     from jax.sharding import PartitionSpec as P
     from repro.core.act_compress import int8_psum
 
@@ -230,8 +229,8 @@ def test_int8_allreduce_unit(mesh3, rng):
         return exact, approx
 
     x = jnp.asarray(rng.normal(0, 1, (8, 64, 64)), jnp.float32)
-    fn = shard_map(body, mesh=mesh3, in_specs=(P("model"),),
-                   out_specs=(P("model"), P("model")), check_vma=True)
+    fn = jax.shard_map(body, mesh=mesh3, in_specs=(P("model"),),
+                       out_specs=(P("model"), P("model")))
     exact, approx = fn(x)
     e, a = np.asarray(exact), np.asarray(approx)
     rel = np.abs(e - a) / (np.abs(e).max() + 1e-9)

@@ -242,3 +242,47 @@ def test_grad_accumulation_matches_full_batch(mesh3):
         out[name] = [np.asarray(x, np.float32) for x in tp]
     for a, c in zip(out["full"], out["acc"]):
         np.testing.assert_allclose(a, c, rtol=5e-2, atol=5e-3)
+
+
+def test_device_mesh_matches_device_count():
+    """A run without --smoke lays its mesh over exactly the visible
+    devices; a pod axis of 1 is left out, a wrong product raises."""
+    from repro.launch.mesh import make_device_mesh
+    m = make_device_mesh(2, 2, 2)
+    assert m.axis_names == ("pod", "data", "model")
+    assert dict(m.shape) == {"pod": 2, "data": 2, "model": 2}
+    m = make_device_mesh(1, 4, 2)
+    assert m.axis_names == ("data", "model")
+    with pytest.raises(ValueError, match="visible"):
+        make_device_mesh(1, 1, 1)
+
+
+def test_train_launcher_published_config_path(monkeypatch):
+    """The non-smoke launcher path: mesh from --pod/--data/--model,
+    --batch/--seq-len honoured, --layers cuts depth only, no checkpoint
+    dir means no restarts, and the optimizer state starts in the layout
+    the step returns (so step 1 reuses step 0's compile)."""
+    import argparse
+    import dataclasses
+
+    from repro.configs.qwen2_5_3b import SMOKE
+    from repro.launch import train
+    monkeypatch.setattr(train, "get_config",
+                        lambda arch: dataclasses.replace(SMOKE, num_layers=4))
+    st = train.main(["--arch", "qwen2.5-3b", "--pod", "2", "--data", "2",
+                     "--model", "2", "--layers", "2", "--batch", "8",
+                     "--seq-len", "64", "--steps", "2", "--ckpt-dir", ""])
+    assert st.run.model.num_layers == 2
+    assert st.run.model.d_model == SMOKE.d_model
+    assert (st.run.shape.global_batch, st.run.shape.seq_len) == (8, 64)
+    assert dict(st.mesh.shape) == {"pod": 2, "data": 2, "model": 2}
+    assert st.result["restarts"] == 0
+    assert all(np.isfinite(r["loss"]) for r in st.metrics_log)
+    want = st.bundle.state_shardings()["opt"]
+    for x, sh in zip(st.opt["m"], want["m"]):
+        assert x.sharding.is_equivalent_to(sh, x.ndim)
+    deeper = argparse.Namespace(smoke=False, arch="qwen2.5-3b", pod=2,
+                                data=2, model=2, cell="train_4k",
+                                seq_len=64, batch=8, layers=5)
+    with pytest.raises(ValueError, match="has 4 layers"):
+        train.build(deeper)
