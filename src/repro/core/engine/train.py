@@ -220,11 +220,13 @@ def _build_parts(bundle):
     def loss_fn_of(train_params, frozen_params, batch):
         params = bundle.merge(train_params, frozen_params)
         loss_sum, cnt, aux = model.loss_fn(params, batch)
-        loss_sum = jax.lax.psum(loss_sum, dp_axes) if dp_axes else loss_sum
-        cnt = jax.lax.psum(cnt, dp_axes) if dp_axes else cnt
-        aux = jax.lax.psum(aux, dp_axes) if dp_axes else aux
-        ce = loss_sum / jnp.maximum(cnt, 1.0)
-        aux_n = aux / jnp.maximum(cnt, 1.0)
+        with jax.named_scope("loss"):
+            loss_sum = (jax.lax.psum(loss_sum, dp_axes) if dp_axes
+                        else loss_sum)
+            cnt = jax.lax.psum(cnt, dp_axes) if dp_axes else cnt
+            aux = jax.lax.psum(aux, dp_axes) if dp_axes else aux
+            ce = loss_sum / jnp.maximum(cnt, 1.0)
+            aux_n = aux / jnp.maximum(cnt, 1.0)
         return ce + aux_n, (ce, aux_n, cnt)
 
     def mb_slice(x, i):
@@ -315,7 +317,12 @@ def _build_parts(bundle):
         reduce-scatter, global-norm clip, AdamW on shards, widened
         updated-shard all-gather. One call site per schedule so the op
         order (and therefore the bits) are identical whether the
-        epilogue runs fused or carried across the step boundary."""
+        epilogue runs fused or carried across the step boundary. Its
+        ops carry the program scope ``optimizer``."""
+        with jax.named_scope("optimizer"):
+            return _epilogue(grads, opt_state)
+
+    def _epilogue(grads, opt_state):
         if widen:
             grads = [rs_widen(g, *widen[j]) if j in widen else g
                      for j, g in enumerate(grads)]

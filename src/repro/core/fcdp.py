@@ -143,9 +143,15 @@ def gather_stage1(w: jax.Array, plan: GatherPlan) -> jax.Array:
     """Stage 1 (inter / DCN) all-gather only: shard -> cached shard.
 
     Identity when the plan has no inter axes (single pod, MiCS,
-    FCDP-Comm frozen layout). Must run inside shard_map."""
+    FCDP-Comm frozen layout). Must run inside shard_map. Its ops carry
+    the program scope ``fcdp.gather1``."""
     if not plan.is_gathered or not plan.inter_axes:
         return w
+    with jax.named_scope("fcdp.gather1"):
+        return _stage1(w, plan)
+
+
+def _stage1(w: jax.Array, plan: GatherPlan) -> jax.Array:
     # the residency layer guarantees a non-trainable leaf never carries a
     # quantized transport (ParamResidency enforces it at construction),
     # so the compression branches need no local frozen re-derivation
@@ -173,9 +179,15 @@ def gather_stage2(w: jax.Array, plan: GatherPlan) -> jax.Array:
     cache boundary is marked identically (so the remat placement is
     unchanged) but the intra gather -- and with it the FULL_NAME mark,
     since no full weight ever materializes -- is deferred into the
-    consuming matmul's ring."""
+    consuming matmul's ring. Its ops carry the program scope
+    ``fcdp.gather2``."""
     if not plan.is_gathered:
         return w
+    with jax.named_scope("fcdp.gather2"):
+        return _stage2(w, plan)
+
+
+def _stage2(w: jax.Array, plan: GatherPlan):
     if plan.cache_after == 1:
         w = checkpoint_name(w, cache_name(plan))
     if plan.is_fused and plan.intra_axes:

@@ -81,16 +81,22 @@ class ShardedLoader:
         return out
 
     def get(self, step: int):
-        b = self.ds.batch_np(step)
-        if self.enc_embed_dim:
-            rng = np.random.default_rng(
-                np.random.SeedSequence([17, self.ds.data.seed, step]))
-            B = self.ds.cell.global_batch
-            S = max(self.ds.cell.seq_len // 4, 8)
-            b["enc_embeds"] = rng.standard_normal(
-                (B, S, self.enc_embed_dim)).astype(np.float32)
-            b["enc_embeds"] = b["enc_embeds"].astype(jnp.bfloat16)
-        return self._place(b)
+        """The batch of ``step``, placed on the mesh. Host spans on the
+        profiler's clock: ``data.get`` around ``data.make`` (the host
+        batch) and ``data.place`` (its transfer)."""
+        with jax.profiler.TraceAnnotation("data.get"):
+            with jax.profiler.TraceAnnotation("data.make"):
+                b = self.ds.batch_np(step)
+                if self.enc_embed_dim:
+                    rng = np.random.default_rng(
+                        np.random.SeedSequence([17, self.ds.data.seed, step]))
+                    B = self.ds.cell.global_batch
+                    S = max(self.ds.cell.seq_len // 4, 8)
+                    b["enc_embeds"] = rng.standard_normal(
+                        (B, S, self.enc_embed_dim)).astype(np.float32)
+                    b["enc_embeds"] = b["enc_embeds"].astype(jnp.bfloat16)
+            with jax.profiler.TraceAnnotation("data.place"):
+                return self._place(b)
 
     def __iter__(self) -> Iterator:
         step = 0

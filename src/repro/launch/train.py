@@ -17,6 +17,7 @@ monitoring, and optional failure injection (--fail-at). An empty
 from __future__ import annotations
 
 import argparse
+import contextlib
 import dataclasses
 import functools
 import time
@@ -35,6 +36,7 @@ from repro.launch.cli import (add_mesh_args, add_system_args,
                               init_compile_cache, system_config_from_args)
 from repro.launch.mesh import make_device_mesh, make_smoke_mesh
 from repro.optim.adamw import init_opt_state
+from repro.runtime import lowerings
 from repro.runtime.elastic import mesh_meta, reshard_state
 from repro.runtime.fault_tolerance import (FailureInjector, HeartbeatMonitor,
                                            StragglerMonitor,
@@ -71,10 +73,21 @@ def build(args):
 
 
 class RunState:
+    """One training run's state and step. ``counters`` holds what the
+    run counts of itself: ``setup_s`` (seconds per set-up phase, each
+    also a host span ``setup.<phase>``), ``init_peak_bytes`` (the most
+    device memory in use at any time up to the end of set-up, over the
+    mesh's devices; None where the backend keeps no memory statistics)
+    and ``step_lowerings`` (per ``do_train_step`` call, the program
+    lowerings it triggered: 0 on a warm step)."""
+
     def __init__(self, run, mesh, args):
         self.run, self.mesh, self.args = run, mesh, args
-        self.bundle = StepBundle(run, mesh)
-        self.step_fn = self.bundle.make_train_step()
+        self.counters = {"setup_s": {}, "init_peak_bytes": None,
+                         "step_lowerings": []}
+        with self._setup_phase("bundle"):
+            self.bundle = StepBundle(run, mesh)
+            self.step_fn = self.bundle.make_train_step()
         # cross-step pipeline (stream 3): the steady-state step carries
         # the previous step's optimizer epilogue; prime fills the
         # pipeline, flush drains it (end of run / before checkpoints)
@@ -86,14 +99,21 @@ class RunState:
         if self.cross_step:
             self.prime_fn = self.bundle.make_train_prime()
             self.flush_fn = self.bundle.make_train_flush()
-        params = self.bundle.init_all_params(seed=run.seed)
-        self.train_p, self.frozen_p = self.bundle.split(params)
-        # placed like the step's own opt outputs: left to the compiler,
-        # the zero moments come out replicated (a full copy per chip)
-        # and the step compiles a second time for the next step's layout
-        self.opt = jax.jit(functools.partial(
-            init_opt_state, sys=run.system),
-            out_shardings=self.bundle.state_shardings()["opt"])(self.train_p)
+        with self._setup_phase("init_params"):
+            params = self.bundle.init_all_params(seed=run.seed)
+            self.train_p, self.frozen_p = self.bundle.split(params)
+            jax.block_until_ready(params)
+        with self._setup_phase("init_opt"):
+            # placed like the step's own opt outputs: left to the
+            # compiler, the zero moments come out replicated (a full
+            # copy per chip) and the step compiles a second time for the
+            # next step's layout
+            self.opt = jax.jit(functools.partial(
+                init_opt_state, sys=run.system),
+                out_shardings=self.bundle.state_shardings()["opt"])(
+                    self.train_p)
+            jax.block_until_ready(self.opt)
+        self.counters["init_peak_bytes"] = _peak_bytes(mesh.devices.flat)
         ds = SyntheticPackedLM(run.model, run.shape, DataConfig(run.seed))
         enc_dim = run.model.d_model if run.model.num_encoder_layers else 0
         self.loader = ShardedLoader(ds, mesh,
@@ -112,17 +132,28 @@ class RunState:
         row's 0.0 as a real norm."""
         self.last_primed = False
         self.steps_taken += 1
-        if not self.cross_step:
-            self.train_p, self.opt, m = self.step_fn(
-                self.train_p, self.frozen_p, self.opt, batch)
-        elif self.carry is None:
-            self.last_primed = True
-            self.carry, m = self.prime_fn(
-                self.train_p, self.frozen_p, self.opt, batch)
-        else:
-            self.train_p, self.opt, self.carry, m = self.step_fn(
-                self.train_p, self.frozen_p, self.opt, self.carry, batch)
+        n0 = lowerings.count()
+        with jax.profiler.TraceAnnotation("train.step"):
+            if not self.cross_step:
+                self.train_p, self.opt, m = self.step_fn(
+                    self.train_p, self.frozen_p, self.opt, batch)
+            elif self.carry is None:
+                self.last_primed = True
+                self.carry, m = self.prime_fn(
+                    self.train_p, self.frozen_p, self.opt, batch)
+            else:
+                self.train_p, self.opt, self.carry, m = self.step_fn(
+                    self.train_p, self.frozen_p, self.opt, self.carry,
+                    batch)
+        self.counters["step_lowerings"].append(lowerings.count() - n0)
         return m
+
+    @contextlib.contextmanager
+    def _setup_phase(self, name: str):
+        t0 = time.perf_counter()
+        with jax.profiler.TraceAnnotation(f"setup.{name}"):
+            yield
+        self.counters["setup_s"][name] = time.perf_counter() - t0
 
     def flush_carry(self):
         """Finalize the outstanding cross-step epilogue, if any, so
@@ -152,6 +183,15 @@ class RunState:
         # the next do_train_step re-primes
         self.carry = tree.get("carry")
         self.steps_taken = 0
+
+
+def _peak_bytes(devices):
+    """The largest ``peak_bytes_in_use`` over ``devices``; None where the
+    backend keeps no memory statistics (the CPU)."""
+    stats = [d.memory_stats() for d in devices]
+    if not all(stats):
+        return None
+    return max(int(m.get("peak_bytes_in_use", 0)) for m in stats)
 
 
 def main(argv=None):

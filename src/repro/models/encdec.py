@@ -89,21 +89,24 @@ class EncDec:
         enc_out = self._encode(params, batch["enc_embeds"])
         ids, labels = batch["ids"], batch["labels"]
         S = ids.shape[1]
-        table = gather_param(params["embed"], self._plans["embed"])
-        x = embed_lookup(table, ids, mi).astype(
-            jnp.dtype(sys.compute_dtype))
+        with jax.named_scope("embed"):
+            table = gather_param(params["embed"], self._plans["embed"])
+            x = embed_lookup(table, ids, mi).astype(
+                jnp.dtype(sys.compute_dtype))
         ctx = {"positions": jnp.arange(S)[None, :], "causal": True,
                "enc_out": enc_out}
         x, _, aux = stk.apply_stack(cfg, sys, mi, self.plan_dec,
                                     params["dec_blocks"],
                                     self._plans["dec_blocks"], x, ctx,
                                     strategy=self.strategy)
-        x = rms_norm(x, gather_param(params["final_norm"],
-                                     self._plans["final_norm"]), cfg.norm_eps)
-        head = gather_param(params["head"], self._plans["head"])
-        loss_sum, cnt = chunked_tp_softmax_xent(
-            x, head, labels, mi, cfg.vocab_size, sys.loss_chunk,
-            batch.get("mask"))
+        with jax.named_scope("loss"):
+            x = rms_norm(x, gather_param(params["final_norm"],
+                                         self._plans["final_norm"]),
+                         cfg.norm_eps)
+            head = gather_param(params["head"], self._plans["head"])
+            loss_sum, cnt = chunked_tp_softmax_xent(
+                x, head, labels, mi, cfg.vocab_size, sys.loss_chunk,
+                batch.get("mask"))
         return loss_sum, cnt, aux
 
     def init_decode_state(self, batch_local: int, max_len: int,

@@ -96,10 +96,12 @@ class LM:
     # -- shared forward pieces ----------------------------------------------
     def _embed(self, params, ids):
         cfg = self.cfg
-        table = gather_param(params["embed"], self._plans["embed"])
-        scale = math.sqrt(cfg.d_model) if cfg.name.startswith("gemma") else 1.0
-        x = embed_lookup(table, ids, self.mi, scale=scale)
-        return x.astype(jnp.dtype(self.sys.compute_dtype))
+        with jax.named_scope("embed"):
+            table = gather_param(params["embed"], self._plans["embed"])
+            scale = (math.sqrt(cfg.d_model) if cfg.name.startswith("gemma")
+                     else 1.0)
+            x = embed_lookup(table, ids, self.mi, scale=scale)
+            return x.astype(jnp.dtype(self.sys.compute_dtype))
 
     def _head_weights(self, params):
         if self.cfg.tie_embeddings:
@@ -152,11 +154,13 @@ class LM:
         x = self._embed(params, ids)
         ctx = {"positions": jnp.arange(S)[None, :], "causal": True}
         x, _, aux = self._run_blocks(params, x, ctx)
-        x = rms_norm(x, gather_param(params["final_norm"],
-                                     self._plans["final_norm"]), cfg.norm_eps)
-        head = self._head_weights(params)
-        loss_sum, cnt = chunked_tp_softmax_xent(
-            x, head, labels, mi, cfg.vocab_size, sys.loss_chunk, mask)
+        with jax.named_scope("loss"):
+            x = rms_norm(x, gather_param(params["final_norm"],
+                                         self._plans["final_norm"]),
+                         cfg.norm_eps)
+            head = self._head_weights(params)
+            loss_sum, cnt = chunked_tp_softmax_xent(
+                x, head, labels, mi, cfg.vocab_size, sys.loss_chunk, mask)
         return loss_sum, cnt, aux
 
     # -- serving -------------------------------------------------------------

@@ -50,6 +50,9 @@ KIND_DEFS = {
 
 STATEFUL_KINDS = ("attn", "xattn", "mamba", "rwkv_tm", "rwkv_cm")
 
+# the program scope of each sublayer kind's ops; other kinds by their name
+SCOPES = {"attn": "attention"}
+
 
 def group_defs(cfg: ModelConfig, plan: List[Tuple[str, ...]], tp: int,
                sys: Optional[SystemConfig] = None
@@ -79,7 +82,13 @@ def stack_defs(defs, n_groups: int):
 
 def apply_sublayer(kind: str, cfg, sys, mi, p, x, ctx: Dict[str, Any],
                    state=None):
-    """Dispatch one sublayer. Returns (x, new_state, aux)."""
+    """Dispatch one sublayer under its program scope (``SCOPES``).
+    Returns (x, new_state, aux)."""
+    with jax.named_scope(SCOPES.get(kind, kind)):
+        return _dispatch(kind, cfg, sys, mi, p, x, ctx, state)
+
+
+def _dispatch(kind: str, cfg, sys, mi, p, x, ctx: Dict[str, Any], state):
     if kind == "attn":
         if ctx.get("paged"):
             x, new_state = sl.attn_paged(
@@ -265,5 +274,8 @@ def apply_stack(cfg: ModelConfig, sys: SystemConfig, mi: MeshInfo,
     # pre-gathering them would break its partial-contraction math
     sched = GatherScheduler(strategy, sys, mi, stacked_plans,
                             enabled=not moe_sharded)
-    return sched.run(make_group_body, wrap, stacked_params, x, aux0,
-                     stacked_state)
+    # ops under ``stack`` and no inner scope are the scan's own slicing
+    # and stashing of the stacked buffers
+    with jax.named_scope("stack"):
+        return sched.run(make_group_body, wrap, stacked_params, x, aux0,
+                         stacked_state)
