@@ -37,16 +37,18 @@ from collections import defaultdict
 from dataclasses import dataclass, field
 from typing import Dict, FrozenSet, List, Optional, Tuple
 
+import jax
+
 from benchmarks.chip import trace
 
 SCOPES = ("stack", "attention", "mlp", "moe", "mamba", "xattn", "rwkv_tm",
           "rwkv_cm", "fcdp.gather1", "fcdp.gather2", "embed", "loss",
           "optimizer")
-PROGRAM_SPANS = ("data.get", "data.make", "data.place", "train.step",
-                 "setup.bundle", "setup.init_params", "setup.init_opt")
+PROGRAM_SPANS = ("data.get", "data.make", "data.place")
 
 _INSTR = re.compile(r"^\s*(?:ROOT\s+)?%?([\w.\-]+) = .*?\s([a-z][a-z0-9\-]*)\(")
 _OP_NAME = re.compile(r'op_name="([^"]*)"')
+_LOC = re.compile(r'loc\("([^"]*)"')
 _CALLED = re.compile(r"(?:calls|to_apply)=%?([\w.\-]+)")
 _OPERAND = re.compile(r"%([\w.\-]+)")
 _WRAPPED = re.compile(r"[\w\-]+\((.*)\)")
@@ -138,6 +140,33 @@ def metadata(hlo_text: str) -> Metadata:
                                  == "fusion" for n in body[c])
 
     return Metadata(out, frozenset(n for n in opcode if runs_collective(n)))
+
+
+def compiled_text(fn, *args) -> str:
+    """The compiled text of the jitted ``fn`` at ``args``, with the
+    ``op_name`` metadata of this program. JAX's persistent compilation
+    cache keys a program without its metadata, so the same step compiled
+    earlier with other scopes (by a checkout of the program without
+    them, sharing the cache) comes back from it under the same
+    instruction names. Where its metadata names a program scope and the
+    program does not, or the other way round, the step is compiled again
+    with that cache off."""
+    lowered = fn.lower(*args)
+    text = lowered.compile().as_text()
+    program = lowered.as_text(debug_info=True)
+    if (any(map(scope_of, _LOC.findall(program)))
+            == any(map(scope_of, _OP_NAME.findall(text)))):
+        return text
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    was = jax.config.jax_enable_compilation_cache
+    jax.clear_caches()      # the lowering keeps its executable in memory
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    try:
+        return fn.lower(*args).compile().as_text()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", was)
+        cc.reset_cache()
 
 
 def _unwrap(segment: str) -> str:
@@ -298,7 +327,7 @@ class ScopedRun:
         per_call = (self.counters or {}).get("step_lowerings")
         if per_call is None or len(per_call) < self.steps:
             return None
-        return sum(per_call[len(per_call) - self.steps:])
+        return sum(list(per_call)[len(per_call) - self.steps:])
 
     def table(self, top: int = 25) -> dict:
         """ms per step by scope and pass, recomputed, and the ``top``
@@ -319,8 +348,8 @@ class ScopedRun:
         train step, as ``trace.RunTrace.read`` reads them."""
         path = glob.glob(f"{tdir}/**/*.xplane.pb", recursive=True)[0]
         tr = trace.Trace.from_xplane(path)
-        text = st.step_fn.lower(st.train_p, st.frozen_p, st.opt,
-                                st.loader.get(0)).compile().as_text()
+        text = compiled_text(st.step_fn, st.train_p, st.frozen_p, st.opt,
+                             st.loader.get(0))
         lo, hi = tr.window()
         return cls(base=base, red=reduce_scopes(tr, metadata(text)),
                    spans=[s for s in program_spans(path) if lo <= s[1] < hi],

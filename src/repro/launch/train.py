@@ -17,6 +17,7 @@ monitoring, and optional failure injection (--fail-at). An empty
 from __future__ import annotations
 
 import argparse
+import collections
 import contextlib
 import dataclasses
 import functools
@@ -72,19 +73,24 @@ def build(args):
     return RunState(run, mesh, args)
 
 
+# how many of the latest do_train_step calls ``step_lowerings`` keeps
+LOWERINGS_KEPT = 1024
+
+
 class RunState:
     """One training run's state and step. ``counters`` holds what the
-    run counts of itself: ``setup_s`` (seconds per set-up phase, each
-    also a host span ``setup.<phase>``), ``init_peak_bytes`` (the most
-    device memory in use at any time up to the end of set-up, over the
-    mesh's devices; None where the backend keeps no memory statistics)
-    and ``step_lowerings`` (per ``do_train_step`` call, the program
-    lowerings it triggered: 0 on a warm step)."""
+    run counts of itself: ``setup_s`` (seconds per set-up phase),
+    ``init_peak_bytes`` (the most device memory in use at any time up
+    to the end of set-up, over the mesh's devices; None where the
+    backend keeps no memory statistics) and ``step_lowerings`` (for each
+    of the latest ``LOWERINGS_KEPT`` ``do_train_step`` calls, the
+    program lowerings it triggered: 0 on a warm step)."""
 
     def __init__(self, run, mesh, args):
         self.run, self.mesh, self.args = run, mesh, args
         self.counters = {"setup_s": {}, "init_peak_bytes": None,
-                         "step_lowerings": []}
+                         "step_lowerings": collections.deque(
+                             maxlen=LOWERINGS_KEPT)}
         with self._setup_phase("bundle"):
             self.bundle = StepBundle(run, mesh)
             self.step_fn = self.bundle.make_train_step()
@@ -133,26 +139,23 @@ class RunState:
         self.last_primed = False
         self.steps_taken += 1
         n0 = lowerings.count()
-        with jax.profiler.TraceAnnotation("train.step"):
-            if not self.cross_step:
-                self.train_p, self.opt, m = self.step_fn(
-                    self.train_p, self.frozen_p, self.opt, batch)
-            elif self.carry is None:
-                self.last_primed = True
-                self.carry, m = self.prime_fn(
-                    self.train_p, self.frozen_p, self.opt, batch)
-            else:
-                self.train_p, self.opt, self.carry, m = self.step_fn(
-                    self.train_p, self.frozen_p, self.opt, self.carry,
-                    batch)
+        if not self.cross_step:
+            self.train_p, self.opt, m = self.step_fn(
+                self.train_p, self.frozen_p, self.opt, batch)
+        elif self.carry is None:
+            self.last_primed = True
+            self.carry, m = self.prime_fn(
+                self.train_p, self.frozen_p, self.opt, batch)
+        else:
+            self.train_p, self.opt, self.carry, m = self.step_fn(
+                self.train_p, self.frozen_p, self.opt, self.carry, batch)
         self.counters["step_lowerings"].append(lowerings.count() - n0)
         return m
 
     @contextlib.contextmanager
     def _setup_phase(self, name: str):
         t0 = time.perf_counter()
-        with jax.profiler.TraceAnnotation(f"setup.{name}"):
-            yield
+        yield
         self.counters["setup_s"][name] = time.perf_counter() - t0
 
     def flush_carry(self):

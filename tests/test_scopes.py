@@ -17,6 +17,7 @@ from repro.configs.base import (OptimizerConfig, RunConfig, ShapeCell,
 from repro.configs.registry import get_smoke_config
 from repro.core.engine import StepBundle
 from repro.launch.mesh import make_mesh
+from repro.launch.train import LOWERINGS_KEPT
 from repro.runtime import lowerings
 
 CELL = ShapeCell("scopes", "train", 256, 4)
@@ -113,10 +114,20 @@ def _strip(text):
 
 
 def test_scopes_change_only_metadata(steps, monkeypatch):
+    from jax.experimental.compilation_cache import compilation_cache as cc
     with_scopes = steps["lora"]
     monkeypatch.setattr(jax, "named_scope",
                         lambda name: contextlib.nullcontext())
-    without = compiled_text("qwen2.5-3b", "fcdp", True, 1)
+    # the persistent cache's key leaves out the metadata: a cache left on
+    # by an earlier test would hand back the scoped step
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    try:
+        without = compiled_text("qwen2.5-3b", "fcdp", True, 1)
+    finally:
+        jax.config.update("jax_enable_compilation_cache", was)
+        cc.reset_cache()
     assert "attention/" not in without and "attention/" in with_scopes
     assert _strip(with_scopes) == _strip(without)
 
@@ -147,7 +158,7 @@ def test_run_counts_lowerings_per_step(run_state):
     st = run_state
     st.do_train_step(st.loader.get(0))
     st.do_train_step(st.loader.get(1))
-    first, warm = st.counters["step_lowerings"][-2:]
+    first, warm = list(st.counters["step_lowerings"])[-2:]
     assert first >= 1 and warm == 0
     # a batch of another shape recompiles, and the counter names the step
     longer = ShapeCell("scopes", "train", 128, 1)
@@ -157,6 +168,8 @@ def test_run_counts_lowerings_per_step(run_state):
     st.do_train_step(loader.get(2))
     assert st.counters["step_lowerings"][-1] >= 1
     assert len(st.counters["step_lowerings"]) == st.steps_taken
+    # kept for the latest calls only, however long the run
+    assert st.counters["step_lowerings"].maxlen == LOWERINGS_KEPT
 
 
 def test_run_counts_its_set_up(run_state):
