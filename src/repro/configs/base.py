@@ -243,8 +243,13 @@ class SystemConfig:
     # serving: store all weights in the FCDP-Comm frozen layout
     # (pod-replicated, intra-sharded host cache) -> zero DCN traffic/token
     serve_frozen: bool = True
-    # attention implementation: jnp | pallas | pallas_interpret
-    attn_impl: str = "jnp"
+    # attention implementation: 'pallas' runs causal self-attention
+    # without a KV cache on the fused flash-attention kernel where it
+    # applies (a TPU mesh, rows and head_dim that tile it), else the
+    # chunked jnp path; 'jnp' forces the chunked path (the oracle);
+    # 'pallas_interpret' the kernel in the TPU interpreter, any backend
+    # (tests: the interpreter cannot run under the layer remat)
+    attn_impl: str = "pallas"
     # MoE dispatch token chunk (bounds the [E,C,D] buffer)
     moe_token_chunk: int = 8192
     # beyond-paper: keep expert weights resident (ZeRO over pod only) --

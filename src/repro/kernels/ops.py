@@ -8,6 +8,7 @@ SystemConfig.attn_impl / quant_impl / fused_impl.
 """
 from __future__ import annotations
 
+import contextlib
 import functools
 from typing import Optional
 
@@ -47,6 +48,124 @@ def flash_attention(q, k, v, causal: bool = True,
     return flash_attention_fwd(
         q, k, v, causal=causal, softmax_scale=softmax_scale,
         block_q=block_q, block_k=block_k, interpret=interpret)
+
+
+# Tiles of JAX's bundled flash-attention kernel for causal training rows,
+# from a sweep on a TPU v5e at head_dim 128 over rows of 4096 with 16 and
+# 32 heads (one forward and backward: 3.49 and 7.76 ms, against 3.59 and
+# 8.19 with every tile 512, 14.9 and 36.7 with every tile 128): 512 rows
+# of q and of k/v per tile, except 1024 rows of k/v in the dK/dV kernel.
+ATTN_TILE = 512
+ATTN_DKV_TILE_K = 1024
+ATTN_MIN_TILE = 128       # the kernel's lane width: smaller never tiles
+
+
+def _tile(seq_len: int, cap: int) -> int:
+    """The largest power-of-two tile from 128 to ``cap`` dividing
+    ``seq_len`` (a multiple of 128)."""
+    t = cap
+    while seq_len % t:
+        t //= 2
+    return t
+
+
+def attention_blocks(seq_len: int, head_dim: int):
+    """The bundled kernel's block sizes for causal rows of ``seq_len``
+    at ``head_dim``, or None where the kernel does not tile them: a
+    head_dim or a row length that is not a multiple of 128."""
+    if head_dim % ATTN_MIN_TILE or seq_len % ATTN_MIN_TILE:
+        return None
+    from jax.experimental.pallas.ops.tpu.flash_attention import BlockSizes
+    b, bk = _tile(seq_len, ATTN_TILE), _tile(seq_len, ATTN_DKV_TILE_K)
+    return BlockSizes(block_q=b, block_k_major=b, block_k=b, block_b=1,
+                      block_q_major_dkv=b, block_k_major_dkv=bk,
+                      block_k_dkv=bk, block_q_dkv=b, block_k_major_dq=b,
+                      block_k_dq=b, block_q_dq=b)
+
+
+def _vary_like(x, vma):
+    """Type ``x`` as varying over the mesh axes ``vma`` (no data moves)."""
+    missing = tuple(vma - jax.typeof(x).vma)
+    return jax.lax.pcast(x, missing, to="varying") if missing else x
+
+
+@contextlib.contextmanager
+def _bundled_kernel(interpret: bool):
+    """Around a call of a Pallas kernel bundled with JAX: its
+    pallas_calls carry no varying-axes types, so the check is off (the
+    caller types the results); ``interpret`` runs it in the TPU
+    interpreter, whose callbacks ``jax.checkpoint`` cannot hold."""
+    from jax.experimental.pallas import tpu as pltpu
+    from repro.compat import check_vma
+    with check_vma(False), (pltpu.force_tpu_interpret_mode() if interpret
+                            else contextlib.nullcontext()):
+        yield
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
+def _flash_train(q, k, v, softmax_scale, blocks, interpret):
+    from jax.experimental.pallas.ops.tpu.flash_attention import \
+        flash_attention
+    with _bundled_kernel(interpret):
+        out = flash_attention(q, k, v, causal=True, sm_scale=softmax_scale,
+                              block_sizes=blocks)
+    return _vary_like(out, jax.typeof(q).vma)
+
+
+# The bundled kernel is a custom_vjp of its own: these call its forward
+# rule (residuals q, k, v, the output and the f32 row statistics l, m)
+# and its backward rule (the dK/dV and dQ kernels) directly, so that
+# results and residuals take q's varying axes, which k, v and the
+# cotangent share (``causal_attention_train``).
+
+def _flash_train_fwd(q, k, v, softmax_scale, blocks, interpret):
+    from repro.compat import flash_attention_fwd
+    vma = jax.typeof(q).vma
+    with _bundled_kernel(interpret):
+        out, res = flash_attention_fwd(
+            q, k, v, ab=None, segment_ids=None, save_residuals=False,
+            causal=True, sm_scale=softmax_scale, block_sizes=blocks,
+            debug=False)
+    return _vary_like(out, vma), jax.tree.map(
+        lambda r: _vary_like(r, vma), res)
+
+
+def _flash_train_bwd(softmax_scale, blocks, interpret, res, do):
+    from repro.compat import flash_attention_bwd
+    vma = jax.typeof(do).vma
+    with _bundled_kernel(interpret):
+        dq, dk, dv, _, _ = flash_attention_bwd(
+            save_residuals=False, causal=True, sm_scale=softmax_scale,
+            block_sizes=blocks, debug=False, residuals=res, do=do)
+    return tuple(_vary_like(g, vma) for g in (dq, dk, dv))
+
+
+_flash_train.defvjp(_flash_train_fwd, _flash_train_bwd)
+
+
+def causal_attention_train(q, k, v, *, softmax_scale: float,
+                           interpret: bool = False):
+    """Causal self-attention on JAX's bundled Pallas flash-attention
+    kernel, differentiable by its own backward kernels.
+
+    q/k/v: [B, S, H, hd] (kv expanded to H heads), with S and hd tiled
+    by ``attention_blocks``. The scores meet the scale in f32, the
+    softmax statistics are f32 and the probabilities enter the PV
+    product in v's dtype. Blocks above the diagonal are skipped, and the
+    backward saves only q, k, v, the output and the per-row statistics.
+    Works inside a ``shard_map`` with varying-axes checks on;
+    ``interpret`` runs the kernels in the TPU interpreter (any backend,
+    not under ``jax.checkpoint``). Not jit-wrapped: it traces inside
+    the caller's ``shard_map`` body."""
+    S, hd = q.shape[1], q.shape[3]
+    blocks = attention_blocks(S, hd)
+    if blocks is None:
+        raise ValueError(f"rows of {S} at head_dim {hd} do not tile the "
+                         f"flash-attention kernel")
+    vma = frozenset().union(*(jax.typeof(x).vma for x in (q, k, v)))
+    q, k, v = (_vary_like(x, vma).transpose(0, 2, 1, 3) for x in (q, k, v))
+    out = _flash_train(q, k, v, softmax_scale, blocks, interpret)
+    return out.transpose(0, 2, 1, 3)
 
 
 @functools.partial(jax.jit, static_argnames=("chunk", "interpret", "impl"))

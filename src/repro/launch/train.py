@@ -82,9 +82,12 @@ class RunState:
     run counts of itself: ``setup_s`` (seconds per set-up phase),
     ``init_peak_bytes`` (the most device memory in use at any time up
     to the end of set-up, over the mesh's devices; None where the
-    backend keeps no memory statistics) and ``step_lowerings`` (for each
+    backend keeps no memory statistics), ``step_lowerings`` (for each
     of the latest ``LOWERINGS_KEPT`` ``do_train_step`` calls, the
-    program lowerings it triggered: 0 on a warm step)."""
+    program lowerings it triggered: 0 on a warm step) and, once a step
+    has been traced, ``attention_paths`` (the self-attention calls the
+    latest trace of a step ran on the fused kernel and on the chunked
+    path: ``{"kernel": n, "chunked": m}``)."""
 
     def __init__(self, run, mesh, args):
         self.run, self.mesh, self.args = run, mesh, args
@@ -138,7 +141,7 @@ class RunState:
         row's 0.0 as a real norm."""
         self.last_primed = False
         self.steps_taken += 1
-        n0 = lowerings.count()
+        n0, paths0 = lowerings.count(), lowerings.attention_paths()
         if not self.cross_step:
             self.train_p, self.opt, m = self.step_fn(
                 self.train_p, self.frozen_p, self.opt, batch)
@@ -150,6 +153,10 @@ class RunState:
             self.train_p, self.opt, self.carry, m = self.step_fn(
                 self.train_p, self.frozen_p, self.opt, self.carry, batch)
         self.counters["step_lowerings"].append(lowerings.count() - n0)
+        paths = {p: n - paths0[p]
+                 for p, n in lowerings.attention_paths().items()}
+        if any(paths.values()):      # this call traced the step
+            self.counters["attention_paths"] = paths
         return m
 
     @contextlib.contextmanager

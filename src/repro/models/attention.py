@@ -1,6 +1,8 @@
-"""Attention: GQA/MQA with chunked (flash-style) jnp implementation,
-plus decode paths (batch-sharded KV and sequence-sharded KV for
-long-context with partial-softmax psum reconstruction).
+"""Attention: GQA/MQA on a fused Pallas flash-attention kernel for
+causal training rows on a TPU, a chunked (flash-style) jnp
+implementation everywhere else, plus decode paths (batch-sharded KV
+and sequence-sharded KV for long-context with partial-softmax psum
+reconstruction).
 
 TP layout: q heads column-parallel over 'model' (padded to a multiple of
 tp); K/V projections replicated over 'model' (GQA kv-head counts are not
@@ -16,8 +18,10 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import ops as kops
 from repro.models.common import (MeshInfo, local_head_mask, psum_tp,
                                  psum_tp_act)
+from repro.runtime.lowerings import ATTENTION_EVENT
 
 NEG_INF = -1e30
 
@@ -138,16 +142,32 @@ def chunked_causal_attention(q, k, v, *, q_chunk: int = 1024,
     return out[:, :Sq].astype(q.dtype)
 
 
-def _lora_term(x, lora, name, scale):
+def project(x, w, bias, lora, name, scale):
+    """``x @ w`` (+ ``bias``) (+ the adapter's term where ``lora`` has
+    one for ``name``). With an adapter, its two products stay in f32 and
+    the three terms are summed in f32 and rounded once to x's dtype, as
+    the f32 reference computes them: after a few lr-sized updates of B
+    the adapter's term lies far below the bf16 step of the product, and
+    added to an already rounded product and rounded again it is lost or
+    kept by how the compiler fuses the add. ``w`` may be a
+    ``core.fcdp.FusedParam``, whose ring product arrives rounded."""
+    from repro.core.fcdp import FusedParam
+    from repro.models.layers import matmul
     a = lora.get(f"{name}_lora_a") if lora else None
     if a is None:
-        return None
-    b = lora[f"{name}_lora_b"]
-    return ((x @ a) @ b) * scale
+        y = matmul(x, w)
+        return y if bias is None else y + bias
+    y = (matmul(x, w).astype(jnp.float32) if isinstance(w, FusedParam)
+         else jnp.dot(x, w, preferred_element_type=jnp.float32))
+    t = jnp.dot(jnp.dot(x, a, preferred_element_type=jnp.float32),
+                lora[f"{name}_lora_b"].astype(jnp.float32),
+                precision=jax.lax.Precision.HIGHEST)
+    y = y + t * scale
+    return (y if bias is None else y + bias).astype(x.dtype)
 
 
 def attention_block(x, wq, wk, wv, wo, bq, bk, bv, cfg, mi: MeshInfo,
-                    positions, attn_impl: str = "jnp",
+                    positions, attn_impl: str = "pallas",
                     kv_cache: Optional[Tuple] = None,
                     paged_kv: Optional[Tuple] = None,
                     q_norm=None, k_norm=None, lora=None,
@@ -169,6 +189,13 @@ def attention_block(x, wq, wk, wv, wo, bq, bk, bv, cfg, mi: MeshInfo,
     be the per-row absolute positions [B, S] (contiguous per row).
     Returns (pool_k, pool_v) as new_kv. Mutually exclusive with
     kv_cache.
+
+    Causal self-attention without a cache runs on the fused
+    flash-attention kernel (``kernels.ops.causal_attention_train``)
+    where ``attn_impl`` is not 'jnp', the mesh's devices are TPUs (or
+    ``attn_impl`` is 'pallas_interpret') and S and hd tile the kernel;
+    every other call runs ``chunked_causal_attention``. Each trace of a
+    call records the path it took (``runtime/lowerings.py``).
     """
     B, S, D = x.shape
     hd = cfg.resolved_head_dim()
@@ -176,24 +203,8 @@ def attention_block(x, wq, wk, wv, wo, bq, bk, bv, cfg, mi: MeshInfo,
     h_local = wq.shape[1] // hd
     padded_heads = h_local * mi.tp
 
-    q = x @ wq
-    if bq is not None:
-        q = q + bq
-    k = x @ wk
-    v = x @ wv
-    if bk is not None:
-        k = k + bk
-    if bv is not None:
-        v = v + bv
-    for name, ref in (("wq", "q"), ("wk", "k"), ("wv", "v")):
-        t = _lora_term(x, lora, name, lora_alpha)
-        if t is not None:
-            if ref == "q":
-                q = q + t.astype(q.dtype)
-            elif ref == "k":
-                k = k + t.astype(k.dtype)
-            else:
-                v = v + t.astype(v.dtype)
+    q, k, v = (project(x, w, b, lora, n, lora_alpha) for w, b, n
+               in ((wq, bq, "wq"), (wk, bk, "wk"), (wv, bv, "wv")))
     q = q.reshape(B, S, h_local, hd)
     k = k.reshape(B, S, n_kv, hd)
     v = v.reshape(B, S, n_kv, hd)
@@ -287,12 +298,19 @@ def attention_block(x, wq, wk, wv, wo, bq, bk, bv, cfg, mi: MeshInfo,
         q_offset = 0
         k_exp, v_exp = slice_expand_kv(k, v, h_local, n_rep, mi)
 
-    if (attn_impl in ("pallas", "pallas_interpret") and causal
-            and kv_cache is None and paged_kv is None):
-        from repro.kernels import ops as kops
-        out = kops.flash_attention(
-            q, k_exp, v_exp, causal=True,
-            interpret=(attn_impl == "pallas_interpret"))
+    interpret = attn_impl == "pallas_interpret"
+    on_kernel = (attn_impl != "jnp" and causal and kv_cache is None
+                 and paged_kv is None
+                 and (mi.platform == "tpu" or interpret)
+                 and kops.attention_blocks(S, hd) is not None)
+    jax.monitoring.record_event(ATTENTION_EVENT,
+                                path="kernel" if on_kernel else "chunked")
+    if on_kernel:
+        # the kernel's custom_vjp saves only q, k, v, the output and the
+        # f32 row statistics; the layer's remat recomputes those
+        out = kops.causal_attention_train(
+            q, k_exp, v_exp, softmax_scale=1.0 / math.sqrt(hd),
+            interpret=interpret)
     else:
         # inner remat: recompute attention internals in the backward from
         # (q, k, v), exactly like FlashAttention -- without this the
@@ -306,11 +324,7 @@ def attention_block(x, wq, wk, wv, wo, bq, bk, bv, cfg, mi: MeshInfo,
     mask = local_head_mask(mi, padded_heads, cfg.num_heads)
     out = out * mask[None, None, :, None].astype(out.dtype)
     out = out.reshape(B, S, h_local * hd)
-    from repro.models.layers import matmul
-    y = matmul(out, wo)
-    t = _lora_term(out, lora, "wo", lora_alpha)
-    if t is not None:
-        y = y + t.astype(y.dtype)
+    y = project(out, wo, None, lora, "wo", lora_alpha)
     return psum_tp_act(y, mi), new_cache
 
 

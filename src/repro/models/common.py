@@ -23,13 +23,15 @@ class MeshInfo:
     # quantize/dequantize codepath for the int8 transports
     # (SystemConfig.quant_impl): 'jnp' | 'pallas' | 'pallas_interpret'
     quant_impl: str = "jnp"
+    # the devices' platform ('tpu', 'cpu', ...): which kernels may lower
+    platform: str = "cpu"
 
     @classmethod
     def from_mesh(cls, mesh, act_psum: str = "bf16",
                   quant_impl: str = "jnp") -> "MeshInfo":
         return cls(tuple(mesh.axis_names),
                    tuple(mesh.shape[a] for a in mesh.axis_names),
-                   act_psum, quant_impl)
+                   act_psum, quant_impl, mesh.devices.flat[0].platform)
 
     def size(self, name: str) -> int:
         return self.axis_sizes[self.axis_names.index(name)] if name in self.axis_names else 1

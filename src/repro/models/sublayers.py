@@ -78,7 +78,7 @@ def attn_apply(cfg, sys: SystemConfig, mi: MeshInfo, p, x, positions,
     y, new_cache = attn_mod.attention_block(
         h, p["wq"], p["wk"], p["wv"], p["wo"],
         p.get("bq"), p.get("bk"), p.get("bv"),
-        cfg, mi, positions, attn_impl=getattr(sys, "attn_impl", "jnp"),
+        cfg, mi, positions, attn_impl=sys.attn_impl,
         kv_cache=kv_cache,
         q_norm=p.get("q_norm"), k_norm=p.get("k_norm"),
         causal=causal, **_lora_kwargs(sys, p))

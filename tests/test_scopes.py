@@ -3,8 +3,8 @@ every layer's scope is there, nearly every fusion, dot, convolution and
 collective falls under one, fcdp's stage-1 all-gathers run in the
 forward only (zero3's also in the backward), and the scopes change
 nothing in the compiled program but its metadata. And the counters a
-run keeps of itself: lowerings per step, set-up phases, the memory peak
-at the end of set-up."""
+run keeps of itself: lowerings per step, attention paths per traced
+step, set-up phases, the memory peak at the end of set-up."""
 import contextlib
 import re
 
@@ -170,6 +170,17 @@ def test_run_counts_lowerings_per_step(run_state):
     assert len(st.counters["step_lowerings"]) == st.steps_taken
     # kept for the latest calls only, however long the run
     assert st.counters["step_lowerings"].maxlen == LOWERINGS_KEPT
+
+
+def test_run_counts_attention_paths(run_state):
+    """The step's trace records its self-attention calls by path: all
+    chunked on the CPU; a warm step leaves the count as it was."""
+    st = run_state
+    st.do_train_step(st.loader.get(0))
+    paths = st.counters["attention_paths"]
+    assert paths["kernel"] == 0 and paths["chunked"] >= 1
+    st.do_train_step(st.loader.get(1))
+    assert st.counters["attention_paths"] == paths
 
 
 def test_run_counts_its_set_up(run_state):
