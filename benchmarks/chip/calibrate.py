@@ -99,12 +99,12 @@ def main(argv=None) -> int:
                 row[f] = program_readings(harness, correct, cell, seed,
                                           devices, f)
         t2 = time.perf_counter()
-        ref = reference.train(cell.config, cell.job, cell.mix, seed,
-                              harness.WARMUP_STEPS, devices)
+        ref = reference.train(cell.family, cell.config, cell.job, cell.mix,
+                              seed, harness.WARMUP_STEPS, devices)
         t3 = time.perf_counter()
         if seed in control:
-            c = reference.train(cell.config, cell.job, cell.mix, seed,
-                                harness.WARMUP_STEPS, devices,
+            c = reference.train(cell.family, cell.config, cell.job, cell.mix,
+                                seed, harness.WARMUP_STEPS, devices,
                                 precision="fp8")
             row["control"] = correct.Readings(c.losses, c.grad_norms[0],
                                               c.change_norms)

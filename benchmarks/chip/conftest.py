@@ -1,7 +1,8 @@
 """Fixtures of the benchmark's own tests. They run on the CPU backend
 with virtual devices, never on a chip, at smoke sizes: a copy of the
-benchmark under a temporary root, with two smoke cells added as files
-(a LoRA cell on one device and a full fine-tune on pod=2 x data=2)."""
+benchmark under a temporary root, with three smoke cells added as files
+(a LoRA cell on one device under fcdp and under zero3, and a full
+fine-tune on pod=2 x data=2)."""
 import os
 
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
@@ -32,10 +33,14 @@ def add_smoke_cells(root: Path) -> Path:
                     ignore=shutil.ignore_patterns("__pycache__", "test*"))
     b = root / "benchmarks" / "chip"
     bench = json.loads((REPO / "BENCHMARK.json").read_text())
-    # both smoke cells start from the qwen cell's files: a LoRA job on
-    # one device, and a full fine-tune of a Granite-like configuration
-    # (no bias, untied vocabulary of odd size) on pod=2 x data=2
+    # the smoke cells start from the qwen cell's files: a LoRA job on one
+    # device under fcdp and under zero3 (each with a configuration of its
+    # own, since a pair of configuration and traffic is one cell), and a
+    # full fine-tune of a Granite-like configuration (no bias, untied
+    # vocabulary of odd size) on pod=2 x data=2
     cells = (("qwen-smoke", "qwen-smoke-lora", 1, dict(vocab_size=512), {}),
+             ("qwen-smoke-zero3", "qwen-smoke-lora-zero3", 1,
+              dict(vocab_size=512), dict(mode="zero3")),
              ("granite-smoke", "granite-smoke-full", 4,
               dict(vocab_size=515, attention_bias=False, rope_theta=1e4,
                    rms_norm_eps=1e-5),
@@ -51,9 +56,10 @@ def add_smoke_cells(root: Path) -> Path:
         (b / f"traffic/{traffic}.json").write_text(json.dumps(dict(
             kind="packed_lm", batch=chips, seq_len=256, zipf_a=1.2,
             doc_len_mean=64, eod_token=0)))
-        bench["configs"].append(dict(name=conf, source="smoke", reduced=[],
-                                     file=f"benchmarks/chip/configs/{conf}.json",
-                                     why="smoke size"))
+        if conf not in {c["name"] for c in bench["configs"]}:
+            bench["configs"].append(dict(
+                name=conf, source="smoke", reduced=[], why="smoke size",
+                file=f"benchmarks/chip/configs/{conf}.json"))
         bench["workloads"].append(dict(name=cell, config=conf, traffic=traffic,
                                        chips=chips, why="smoke size"))
     (root / "BENCHMARK.json").write_text(json.dumps(bench, indent=1))

@@ -1,21 +1,11 @@
-"""Operation and byte counts kept with the benchmark, and the table of peaks.
-
-``model_flops_per_token`` is the FLOPs rule of ``mfu``: counted from the
-configuration's shapes, never from what the program happens to run.
-
-- Forward: 2 x every matmul parameter (attention and MLP projections,
-  LoRA adapters, and the LM head, tied or not; the embedding lookup is
-  no matmul), plus causal attention, 4 x (S/2) x heads x head_dim per
-  layer.
-- Backward of a full fine-tune: 2 x forward. Of LoRA: 1 x forward for the
-  activation gradients, plus 2 x the adapter parameters for the
-  adapters' own weight gradients.
-- Recomputation is never counted.
+"""Byte and operation counts of compiled programs, and the table of peaks.
 
 ``hlo_op_costs`` reads a compiled program's text and gives, for each
 instruction that runs as one device op, the FLOPs of the dot and
 convolution instructions it holds and the bytes it reads and writes,
-for the matmul roofline.
+for the matmul roofline. The FLOPs rule of ``mfu``, counted from a
+configuration's shapes, is its family's (``families/<family>.py``
+``model_flops_per_token``).
 """
 from __future__ import annotations
 
@@ -36,44 +26,6 @@ def peaks(device_kind: str) -> dict:
         raise KeyError(f"no peaks for device kind {device_kind!r}; "
                        f"known: {sorted(table)}")
     return table[device_kind]
-
-
-def layer_matmul_params(cfg: dict) -> int:
-    """Matmul parameters of one transformer layer (GQA attention + GLU
-    MLP), from the configuration's published keys."""
-    d = cfg["hidden_size"]
-    hd = cfg.get("head_dim") or d // cfg["num_attention_heads"]
-    q = cfg["num_attention_heads"] * hd
-    kv = cfg["num_key_value_heads"] * hd
-    return d * q + 2 * d * kv + q * d + 3 * d * cfg["intermediate_size"]
-
-
-def adapter_params(cfg: dict, peft: Optional[dict]) -> int:
-    """LoRA parameters of one layer: rank x (in + out) per target."""
-    if not peft:
-        return 0
-    d = cfg["hidden_size"]
-    hd = cfg.get("head_dim") or d // cfg["num_attention_heads"]
-    q = cfg["num_attention_heads"] * hd
-    kv = cfg["num_key_value_heads"] * hd
-    dims = {"wq": (d, q), "wk": (d, kv), "wv": (d, kv), "wo": (q, d)}
-    return sum(peft["rank"] * sum(dims[t]) for t in peft["targets"])
-
-
-def model_flops_per_token(cfg: dict, seq_len: int,
-                          peft: Optional[dict]) -> float:
-    """Training FLOPs per token (forward + backward, no recompute)."""
-    n_layers = cfg["num_hidden_layers"]
-    d = cfg["hidden_size"]
-    hd = cfg.get("head_dim") or d // cfg["num_attention_heads"]
-    adapters = n_layers * adapter_params(cfg, peft)
-    matmul = (n_layers * layer_matmul_params(cfg) + adapters
-              + d * cfg["vocab_size"])
-    attn = n_layers * 4 * (seq_len / 2) * cfg["num_attention_heads"] * hd
-    fwd = 2 * matmul + attn
-    if peft:
-        return 2 * fwd + 2 * adapters
-    return 3 * fwd
 
 
 # ---------------------------------------------------------------------------

@@ -1,17 +1,29 @@
 """The benchmark's engine: find a cell by name, build the program's own
 training run for it, warm it up, time a window, and decide ``correct``.
 
-Everything that belongs to one configuration, one traffic mix, one cell
-or one per-layer metric is a file of its own, found by the name that
-``BENCHMARK.json`` gives it:
+Everything that belongs to one model family, configuration, traffic
+mix, cell or per-layer metric is a file of its own, found by the name
+that ``BENCHMARK.json`` (or the configuration) gives it:
 
-- ``configs/<config>.json``: the model's published keys, as run;
+- ``families/<family>.py``: what is particular to a model family: the
+  program's ``ModelConfig`` (``model_config``), the reference's leaves
+  and layer groups (``param_specs``, ``reference_model``) and the FLOPs
+  rule of ``mfu`` (``model_flops_per_token``);
+- ``configs/<config>.json``: the model's published keys, as run, beside
+  the benchmark's own: ``family`` (the module above; there is no
+  default), ``reduced``, ``cuts``, ``assumed``, ``deployment`` and
+  ``departures``;
 - ``traffic/<traffic>.json``: the batch, row length and generator
   parameters, read by ``traffic.PackedLM``;
 - ``cells/<workload>.json``: the job (strategy, mesh, remat, loss chunk,
-  LoRA, optimizer) and the limits of the comparison that decides
-  ``correct``;
+  LoRA, optimizer, and under ``system`` any further ``SystemConfig``
+  fields, passed as they are) and the limits of the comparison that
+  decides ``correct``;
 - ``metrics/<name>.py``: a reader with ``read(run) -> float | None``.
+
+So a configuration of a new family comes as new files only: its family
+module, its configuration, a cell and its traffic, and readers for any
+metric of its own.
 
 The window drives the program's own path: ``ShardedLoader.get`` and
 ``RunState.do_train_step``, then a read of the step's loss, as
@@ -24,10 +36,12 @@ import gc
 import importlib.util
 import json
 import math
+import re
 import tempfile
 import time
 from dataclasses import dataclass, field
 from pathlib import Path
+from types import ModuleType
 from typing import Dict, List, Optional
 
 import jax
@@ -52,6 +66,7 @@ class Cell:
     mix: dict               # traffic/<traffic>.json
     end_to_end: List[dict]  # BENCHMARK.json entries this cell reports
     per_layer: List[dict]
+    family: ModuleType      # families/<family>.py of the configuration
     root: Path = ROOT
 
     @property
@@ -67,6 +82,29 @@ def _read_json(path: Path) -> dict:
     return json.loads(path.read_text())
 
 
+def _import(path: Path, prefix: str) -> ModuleType:
+    spec = importlib.util.spec_from_file_location(f"{prefix}{path.stem}", path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+def load_family(config_file: Path, cfg: dict, bench_dir: Path) -> ModuleType:
+    """The module ``families/<family>.py`` that the configuration's
+    ``family`` key names. A missing key or module raises; there is no
+    default family."""
+    name = cfg.get("family")
+    if not (isinstance(name, str)
+            and re.fullmatch(r"[A-Za-z0-9_][A-Za-z0-9_.-]*", name)):
+        raise ValueError(f"{config_file}: no \"family\" key naming a module "
+                         f"{bench_dir}/families/<family>.py (got {name!r})")
+    path = bench_dir / "families" / f"{name}.py"
+    if not path.is_file():
+        raise FileNotFoundError(f"{config_file}: family {name!r} has no "
+                                f"module {path}")
+    return _import(path, "bench_family_")
+
+
 def load_cell(workload: str, root: Path = ROOT) -> Cell:
     """The cell ``workload`` as ``BENCHMARK.json`` under ``root`` names
     it, with its configuration, traffic mix and job files."""
@@ -77,6 +115,8 @@ def load_cell(workload: str, root: Path = ROOT) -> Cell:
     w = by_name[workload]
     conf = next(c for c in bench["configs"] if c["name"] == w["config"])
     bdir = root / BENCH_DIR
+    config_file = root / conf["file"]
+    config = _read_json(config_file)
 
     def mine(entry):
         return "workloads" not in entry or workload in entry["workloads"]
@@ -87,11 +127,11 @@ def load_cell(workload: str, root: Path = ROOT) -> Cell:
                  if (workload in m["workloads"] if "workloads" in m
                      else m["moves"] in reported)]
     return Cell(name=workload, chips=int(w["chips"]),
-                config_name=conf["name"],
-                config=_read_json(root / conf["file"]),
+                config_name=conf["name"], config=config,
                 job=_read_json(bdir / "cells" / f"{workload}.json"),
                 mix=_read_json(bdir / "traffic" / f"{w['traffic']}.json"),
-                end_to_end=e2e, per_layer=per_layer, root=root)
+                end_to_end=e2e, per_layer=per_layer,
+                family=load_family(config_file, config, bdir), root=root)
 
 
 def require_tpu(chips: int):
@@ -106,35 +146,6 @@ def require_tpu(chips: int):
     return devs
 
 
-def head_dim(cfg: dict) -> int:
-    return cfg.get("head_dim") or cfg["hidden_size"] // cfg["num_attention_heads"]
-
-
-def model_config(name: str, cfg: dict):
-    """The program's ModelConfig for a configuration file. Keys the
-    program cannot run (Granite's multipliers, MLP biases) must hold
-    their neutral values; the file states what was run."""
-    from repro.configs.base import ModelConfig
-    neutral = {"embedding_multiplier": 1.0, "residual_multiplier": 1.0,
-               "logits_scaling": 1.0, "mlp_bias": False,
-               "attention_multiplier": 1.0 / math.sqrt(head_dim(cfg))}
-    for key, want in neutral.items():
-        if key in cfg and not math.isclose(cfg[key], want, rel_tol=1e-9):
-            raise ValueError(f"{name}: {key}={cfg[key]} cannot be run by the "
-                             f"program's dense model (only {want})")
-    if cfg["hidden_act"] != "silu":
-        raise ValueError(f"{name}: hidden_act {cfg['hidden_act']!r}")
-    return ModelConfig(
-        name=name, family="dense", num_layers=cfg["num_hidden_layers"],
-        d_model=cfg["hidden_size"], num_heads=cfg["num_attention_heads"],
-        num_kv_heads=cfg["num_key_value_heads"],
-        d_ff=cfg["intermediate_size"], vocab_size=cfg["vocab_size"],
-        head_dim=head_dim(cfg), act="swiglu",
-        qkv_bias=bool(cfg.get("attention_bias", False)),
-        rope_theta=float(cfg["rope_theta"]), norm_eps=cfg["rms_norm_eps"],
-        tie_embeddings=bool(cfg["tie_word_embeddings"]))
-
-
 def run_config(cell: Cell, seed: int):
     from repro.configs.base import (OptimizerConfig, RunConfig, ShapeCell,
                                     SystemConfig)
@@ -146,10 +157,12 @@ def run_config(cell: Cell, seed: int):
     sysc = SystemConfig(mode=job["mode"],
                         activation_policy=job["activation_policy"],
                         loss_chunk=job["loss_chunk"],
-                        min_shard_size=job["min_shard_size"], **lora)
+                        min_shard_size=job["min_shard_size"], **lora,
+                        **job.get("system", {}))
     shape = ShapeCell(cell.name, "train", int(cell.mix["seq_len"]),
                       int(cell.mix["batch"]))
-    return RunConfig(model=model_config(cell.config_name, cell.config),
+    return RunConfig(model=cell.family.model_config(cell.config_name,
+                                                    cell.config),
                      shape=shape, system=sysc,
                      optimizer=OptimizerConfig(**job["optimizer"]), seed=seed)
 
@@ -213,7 +226,7 @@ def change_norms(st, cell: Cell, seed: int) -> Dict[str, float]:
     initial value). The initial value is drawn again from the seed by the
     reference's own initialisation, on the device, a leaf at a time."""
     from benchmarks.chip import reference
-    specs = reference.param_specs(cell.config, cell.peft)
+    specs = cell.family.param_specs(cell.config, cell.peft)
     keys = reference.leaf_keys(seed, len(specs))
     index = {s.path: i for i, s in enumerate(specs)}
     out = {}
@@ -265,11 +278,7 @@ def free_state(st) -> None:
 
 
 def load_metric(cell: Cell, name: str):
-    path = cell.bench_dir / "metrics" / f"{name}.py"
-    spec = importlib.util.spec_from_file_location(f"bench_metric_{name}", path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
+    return _import(cell.bench_dir / "metrics" / f"{name}.py", "bench_metric_")
 
 
 def init_cache() -> None:
@@ -332,7 +341,7 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool, devices,
         trace_mod.remove(tdir)
     else:
         tps = win.steps * B * S / win.seconds
-        fpt = flops.model_flops_per_token(cell.config, S, cell.peft)
+        fpt = cell.family.model_flops_per_token(cell.config, S, cell.peft)
         peak_flops = flops.peaks(dev.device_kind)["bf16_flops"]
         values = {"tokens_per_s": tps,
                   "mfu": 100.0 * tps * fpt / (len(devices) * peak_flops),
@@ -345,8 +354,8 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool, devices,
         f"peak {peak} B")
 
     free_state(st)
-    ref = reference.train(cell.config, cell.job, cell.mix, seed,
-                          WARMUP_STEPS, devices)
+    ref = reference.train(cell.family, cell.config, cell.job, cell.mix,
+                          seed, WARMUP_STEPS, devices)
     checks = correct.compare(prog, ref, cell.job["limits"])
     ok = failed == 0 and all(c["value"] <= c["limit"]
                              for c in checks.values())

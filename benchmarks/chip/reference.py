@@ -1,36 +1,35 @@
-"""Plain float32 reference of the dense decoder the cells train.
+"""Plain float32 reference machinery, common to every model family.
 
 It imports nothing of the program and takes nothing the program made.
-Its weights are drawn again from the seed by the rule the program
-documents (``repro.core.partition.init_params``): one key per parameter
-leaf, split from ``key(seed)`` in the order of the leaves' sorted paths;
+A family (``families/<family>.py``) gives its model: the parameter
+leaves (``param_specs``) and ``reference_model``, an ordered list of
+layer groups (``Group``) and the leaves of the embedding, final norm and
+head (``Model``). This module gives what the families share: the leaves'
+initialisation, the pieces of a decoder (RMSNorm, RoPE, blocked causal
+attention, the blocked loss), the fp8 control, and the driver that
+trains a ``Model`` layer by layer.
+
+Weights are drawn again from the seed by the rule the program documents
+(``repro.core.partition.init_params``): one key per parameter leaf,
+split from ``key(seed)`` in the order of the leaves' sorted paths;
 normal leaves are N(0, 1) / sqrt(fan_in) (the embedding N(0, 0.02^2)),
 norms are ones, biases and LoRA B are zeros; every leaf is stored in
 bf16, and the optimizer's f32 master starts as that bf16 value.
 
-The model, from the configuration's published keys: embedding lookup;
-per layer an RMSNorm, GQA attention with RoPE (rotating the two halves of
-each head), optional q/k/v bias, softmax scaled by 1/sqrt(head_dim),
-causal over the whole row, LoRA terms (x @ A) @ B * alpha / rank on the
-targeted projections, a residual add; then an RMSNorm, a SwiGLU MLP
-(silu(h @ W_gate) * (h @ W_in)) @ W_out and a residual add; a final
-RMSNorm, the LM head (the embedding's transpose when tied) and the mean
-cross-entropy over the tokens the mask keeps.
-
-Training: the gradient of that loss, clipped to a global norm, then
-AdamW with bias correction and decoupled weight decay on every
-trainable leaf of two or more stored dimensions except LoRA adapters
-(stacked norm scales included: the program stores a layer's scales as
-one [layers, d] leaf). The forward runs on the bf16 copy of the f32
-masters, as the configuration states its weights (bf16, f32 masters);
-every operation is computed in f32 with matmuls at ``highest``
-precision.
+Training: the gradient of the mean cross-entropy over the tokens the
+mask keeps, clipped to a global norm, then AdamW with bias correction
+and decoupled weight decay on every trainable leaf of two or more
+stored dimensions except LoRA adapters (stacked norm scales included:
+the program stores a layer's scales as one [layers, d] leaf). The
+forward runs on the bf16 copy of the f32 masters, as the configuration
+states its weights (bf16, f32 masters); every operation is computed in
+f32 with matmuls at ``highest`` precision.
 
 It runs layer by layer so that it fits beside nothing else on the chip:
-the forward keeps each layer's input, the backward recomputes one layer
-at a time under ``jax.vjp``, and attention and the loss run in blocks of
-rows. On several devices the arrays are spread over a one-axis mesh and
-XLA partitions the plain program.
+the forward walks the groups in order and keeps each layer's input, the
+backward recomputes one layer at a time under ``jax.vjp``, and attention
+and the loss run in blocks of rows. On several devices the arrays are
+spread over a one-axis mesh and XLA partitions the plain program.
 
 ``precision="fp8"`` is the control: every matmul and attention product
 takes its operands (and, in the backward, its incoming gradient) rounded
@@ -41,7 +40,7 @@ from __future__ import annotations
 
 import functools
 import math
-from typing import Dict, List, NamedTuple, Optional
+from typing import Callable, Dict, List, NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -54,48 +53,33 @@ LOSS_BLOCK = 1024       # loss rows per block
 
 
 class LeafSpec(NamedTuple):
-    path: str           # dotted path, e.g. "blocks.pos0.attn.wq"
+    path: str           # dotted path, e.g. "blocks.<stack>.attn.wq"
     shape: tuple
     init: str           # normal | embed | ones | zeros
     trainable: bool
 
 
-def _dims(cfg: dict):
-    d = cfg["hidden_size"]
-    hd = cfg.get("head_dim") or d // cfg["num_attention_heads"]
-    return (cfg["num_hidden_layers"], d, cfg["num_attention_heads"],
-            cfg["num_key_value_heads"], hd, cfg["intermediate_size"],
-            cfg["vocab_size"])
+class Group(NamedTuple):
+    """A stack of layers of one kind: its leaves are the ones under
+    ``prefix``, each stored as [layers, ...]."""
+    prefix: str         # the stack's path, e.g. "blocks.<stack>"
+    layers: int
+    layer: Callable     # layer(x, p, ein) -> x; p: one layer's weights
+    #                     by their path under the prefix, e.g. "attn.wq"
 
 
-def param_specs(cfg: dict, peft: Optional[dict]) -> List[LeafSpec]:
-    """Every parameter leaf in the order its key is drawn."""
-    L, D, H, KV, hd, F, V = _dims(cfg)
-    full = not peft
-    attn = {"wq": ((L, D, H * hd), "normal"), "wk": ((L, D, KV * hd), "normal"),
-            "wv": ((L, D, KV * hd), "normal"), "wo": ((L, H * hd, D), "normal"),
-            "norm": ((L, D), "ones")}
-    if cfg.get("attention_bias"):
-        attn.update({"bq": ((L, H * hd), "zeros"), "bk": ((L, KV * hd), "zeros"),
-                     "bv": ((L, KV * hd), "zeros")})
-    specs = [LeafSpec(f"blocks.pos0.attn.{k}", s, i, full)
-             for k, (s, i) in attn.items()]
-    for t in (peft or {}).get("targets", ()):
-        (_, d_in, d_out), _ = attn[t]
-        r = peft["rank"]
-        specs += [LeafSpec(f"blocks.pos0.attn.{t}_lora_a", (L, d_in, r),
-                           "normal", True),
-                  LeafSpec(f"blocks.pos0.attn.{t}_lora_b", (L, r, d_out),
-                           "zeros", True)]
-    mlp = {"norm": ((L, D), "ones"), "w_gate": ((L, D, F), "normal"),
-           "w_in": ((L, D, F), "normal"), "w_out": ((L, F, D), "normal")}
-    specs += [LeafSpec(f"blocks.pos0.mlp.{k}", s, i, full)
-              for k, (s, i) in mlp.items()]
-    specs += [LeafSpec("embed", (V, D), "embed", full),
-              LeafSpec("final_norm", (D,), "ones", full)]
-    if not cfg["tie_word_embeddings"]:
-        specs.append(LeafSpec("head", (D, V), "normal", full))
-    return sorted(specs, key=lambda s: s.path.split("."))
+class Model(NamedTuple):
+    """A family's reference model: embedding, groups, final norm, head."""
+    groups: List[Group]     # in the order the forward runs them
+    embed: str              # the embedding table's leaf, [V, D]
+    final_norm: str
+    head: str               # the head's leaf, [D, V]; tied, the embedding
+    #                         table itself, used transposed
+    norm_eps: float         # the final norm's epsilon
+
+    @property
+    def tied(self) -> bool:
+        return self.head == self.embed
 
 
 def leaf_keys(seed: int, n: int):
@@ -165,7 +149,7 @@ def _einsum(precision: str):
 
 
 # ---------------------------------------------------------------------------
-# The model
+# Pieces of a decoder
 # ---------------------------------------------------------------------------
 
 def rms_norm(x, scale, eps):
@@ -204,36 +188,10 @@ def attention(q, k, v, ein):
     return out.swapaxes(0, 1).reshape(B, S, H, hd)
 
 
-def layer(x, p, cfg, lora_scale, ein):
-    """One decoder layer. p: this layer's weights by short name."""
-    _, D, H, KV, hd, _, _ = _dims(cfg)
-    eps, B, S = cfg["rms_norm_eps"], x.shape[0], x.shape[1]
-
-    def proj(h, name):
-        y = ein("bsd,de->bse", h, p[name])
-        if f"{name}_lora_a" in p:
-            y = y + ein("bsr,re->bse", ein("bsd,dr->bsr", h, p[f"{name}_lora_a"]),
-                        p[f"{name}_lora_b"]) * lora_scale
-        return y
-
-    h = rms_norm(x, p["attn.norm"], eps)
-    q, k, v = (proj(h, f"attn.{n}") for n in ("wq", "wk", "wv"))
-    if "attn.bq" in p:
-        q, k, v = q + p["attn.bq"], k + p["attn.bk"], v + p["attn.bv"]
-    q = rope(q.reshape(B, S, H, hd), cfg["rope_theta"])
-    k = rope(k.reshape(B, S, KV, hd), cfg["rope_theta"])
-    o = attention(q, k, v.reshape(B, S, KV, hd), ein).reshape(B, S, H * hd)
-    x = x + proj(o, "attn.wo")
-    h = rms_norm(x, p["mlp.norm"], eps)
-    z = jax.nn.silu(ein("bsd,df->bsf", h, p["mlp.w_gate"])) * ein(
-        "bsd,df->bsf", h, p["mlp.w_in"])
-    return x + ein("bsf,fd->bsd", z, p["mlp.w_out"])
-
-
-def loss_sums(x, final_norm, head, labels, mask, cfg, ein):
+def loss_sums(x, final_norm, head, labels, mask, eps, ein):
     """(sum of masked token cross-entropies, count), blocks of rows."""
     B, S, D = x.shape
-    h = rms_norm(x, final_norm, cfg["rms_norm_eps"])
+    h = rms_norm(x, final_norm, eps)
     nb = S // LOSS_BLOCK if S % LOSS_BLOCK == 0 else 1
 
     @jax.checkpoint
@@ -249,6 +207,119 @@ def loss_sums(x, final_norm, head, labels, mask, cfg, ein):
 
     total = jnp.sum(jax.lax.map(block, (split(h), split(labels), split(mask))))
     return total, jnp.sum(mask.astype(F32))
+
+
+# ---------------------------------------------------------------------------
+# The layer-by-layer driver
+# ---------------------------------------------------------------------------
+
+def group_leaves(model: Model, specs: List[LeafSpec]) -> Dict[str, List[str]]:
+    """{group prefix: the paths of its leaves}; every leaf belongs to a
+    group or is the embedding, final norm or head."""
+    top = {model.embed, model.final_norm, model.head}
+    out = {g.prefix: [s.path for s in specs
+                      if s.path.startswith(g.prefix + ".")]
+           for g in model.groups}
+    claimed = top.union(*out.values())
+    stray = [s.path for s in specs if s.path not in claimed]
+    if stray:
+        raise ValueError(f"leaves in no group of the model: {stray}")
+    return out
+
+
+def layerwise(model: Model, specs: List[LeafSpec], trainable, ein, mesh,
+              row):
+    """The model's loss and its gradient by every leaf in ``trainable``,
+    one layer at a time: ``grad(master, ids, labels, mask) -> (loss,
+    {path: gradient})``, where ``master`` holds every leaf by path and
+    the batch is placed by ``row``."""
+    members = group_leaves(model, specs)
+    rep = NamedSharding(mesh, P())
+
+    def bf16_value(w):
+        # the bf16 copy the forward runs on, whole on every device
+        return jax.lax.with_sharding_constraint(to_bf16(w.astype(F32)), rep)
+
+    def rows(x):
+        return jax.lax.with_sharding_constraint(x, row)
+
+    def passes(group: Group):
+        n = len(group.prefix) + 1
+
+        def weights(stack, l):
+            return {p[n:]: bf16_value(stack[p][l]) for p in stack}
+
+        @jax.jit
+        def fwd(x, stack, l):
+            return rows(group.layer(rows(x), weights(stack, l), ein))
+
+        @jax.jit
+        def bwd(x, train_stack, frozen_stack, l, dy):
+            def f(x_, tp):
+                p = weights(frozen_stack, l)
+                p.update({k[n:]: bf16_value(v) for k, v in tp.items()})
+                return rows(group.layer(rows(x_), p, ein))
+            tp = {k: v[l] for k, v in train_stack.items()}
+            _, vjp = jax.vjp(f, x, tp)
+            return vjp(dy)
+        return fwd, bwd
+
+    fns = [passes(g) for g in model.groups]
+
+    @functools.partial(jax.jit, donate_argnums=0)
+    def put(buf, g, l):
+        return buf.at[l].set(g)
+
+    def head_of(w):
+        w = bf16_value(w)
+        return w.T if model.tied else w
+
+    @jax.jit
+    def top(x, final_norm, hw, labels, mask):
+        def f(x_, fn, hw_):
+            s, c = loss_sums(rows(x_), bf16_value(fn), head_of(hw_),
+                             labels, mask, model.norm_eps, ein)
+            return s / jnp.maximum(c, 1.0)
+        return jax.value_and_grad(f, argnums=(0, 1, 2))(x, final_norm, hw)
+
+    @jax.jit
+    def embed(table, ids):
+        return rows(jnp.take(bf16_value(table), ids, axis=0))
+
+    @jax.jit
+    def embed_grad(table, ids, dx):
+        return jnp.zeros(table.shape, F32).at[ids].add(dx)
+
+    def grad(master, ids, labels, mask):
+        stacks = [({p: master[p] for p in members[g.prefix] if p in trainable},
+                   {p: master[p] for p in members[g.prefix]
+                    if p not in trainable}) for g in model.groups]
+        xs = [embed(master[model.embed], ids)]
+        for g, (fwd, _), (ts, fs) in zip(model.groups, fns, stacks):
+            for l in range(g.layers):
+                xs.append(fwd(xs[-1], {**ts, **fs}, jnp.int32(l)))
+        loss, (dx, g_fn, g_head) = top(xs[-1], master[model.final_norm],
+                                       master[model.head], labels, mask)
+        xs.pop()
+        grads = {p: jnp.zeros_like(v) for ts, _ in stacks
+                 for p, v in ts.items()}
+        if model.final_norm in trainable:
+            grads[model.final_norm] = g_fn
+        for g, (_, bwd), (ts, fs) in reversed(list(zip(model.groups, fns,
+                                                       stacks))):
+            for l in reversed(range(g.layers)):
+                dx, g_l = bwd(xs.pop(), ts, fs, jnp.int32(l), dx)
+                for p in ts:
+                    grads[p] = put(grads[p], g_l[p], jnp.int32(l))
+                del g_l
+        if model.embed in trainable:
+            g_e = embed_grad(master[model.embed], ids, dx)
+            # tied: the head's gradient is already the table's
+            grads[model.embed] = g_e + g_head if model.tied else g_e
+            if not model.tied:
+                grads[model.head] = g_head
+        return loss, grads
+    return grad
 
 
 # ---------------------------------------------------------------------------
@@ -281,22 +352,17 @@ def _sharding(mesh, shape):
     return NamedSharding(mesh, P(*spec))
 
 
-def train(cfg: dict, job: dict, mix: dict, seed: int, steps: int, devices,
-          precision: str = "f32") -> Result:
-    """``steps`` training steps from the seed's weights on the seed's
-    traffic (``traffic.PackedLM``)."""
+def train(family, cfg: dict, job: dict, mix: dict, seed: int, steps: int,
+          devices, precision: str = "f32") -> Result:
+    """``steps`` training steps of ``family``'s model of ``cfg`` from the
+    seed's weights on the seed's traffic (``traffic.PackedLM``)."""
     from benchmarks.chip.traffic import PackedLM
     if job["mesh"]["model"] != 1:
         raise ValueError("the reference draws weights at the program's "
                          "shapes for a model axis of 1 only")
-    L = cfg["num_hidden_layers"]
     peft, opt = job.get("peft") or None, job["optimizer"]
-    lora_scale = peft["alpha"] / peft["rank"] if peft else 0.0
-    tied = cfg["tie_word_embeddings"]
-    head_key = "embed" if tied else "head"
-    ein = _einsum(precision)
     mesh = Mesh(np.asarray(devices), ("x",))
-    specs = param_specs(cfg, peft)
+    specs = family.param_specs(cfg, peft)
     keys = leaf_keys(seed, len(specs))
     data = PackedLM(mix, cfg["vocab_size"], seed)
     batches = [data.batch_np(t) for t in range(steps)]
@@ -304,7 +370,6 @@ def train(cfg: dict, job: dict, mix: dict, seed: int, steps: int, devices,
     row = NamedSharding(mesh, P("x") if B % mesh.size == 0 else P())
 
     with jax.default_matmul_precision("highest"):
-        rep = NamedSharding(mesh, P())
         master = {}
         for k, s in zip(keys, specs):
             # frozen leaves stay in bf16, which holds their values exactly
@@ -315,58 +380,8 @@ def train(cfg: dict, job: dict, mix: dict, seed: int, steps: int, devices,
         trainable = [s.path for s in specs if s.trainable]
         moments = {p: (jnp.zeros_like(master[p]), jnp.zeros_like(master[p]))
                    for p in trainable}
-        blocks = [s.path for s in specs if s.path.startswith("blocks.")]
-        short = {p: p.split(".", 2)[2] for p in blocks}
-
-        def bf16_value(w):
-            # the bf16 copy the forward runs on, whole on every device
-            return jax.lax.with_sharding_constraint(
-                to_bf16(w.astype(F32)), rep)
-
-        def weights(stack, l):
-            return {short[p]: bf16_value(stack[p][l]) for p in stack}
-
-        def rows(x):
-            return jax.lax.with_sharding_constraint(x, row)
-
-        @jax.jit
-        def fwd_layer(x, stack, l):
-            return rows(layer(rows(x), weights(stack, l), cfg, lora_scale,
-                              ein))
-
-        @jax.jit
-        def bwd_layer(x, train_stack, frozen_stack, l, dy):
-            def f(x_, tp):
-                p = weights(frozen_stack, l)
-                p.update({short[k]: bf16_value(v) for k, v in tp.items()})
-                return rows(layer(rows(x_), p, cfg, lora_scale, ein))
-            tp = {k: v[l] for k, v in train_stack.items()}
-            _, vjp = jax.vjp(f, x, tp)
-            return vjp(dy)
-
-        @functools.partial(jax.jit, donate_argnums=0)
-        def put(buf, g, l):
-            return buf.at[l].set(g)
-
-        def head_of(w):
-            w = bf16_value(w)
-            return w.T if tied else w
-
-        @jax.jit
-        def top(x, final_norm, hw, labels, mask):
-            def f(x_, fn, hw_):
-                s, c = loss_sums(rows(x_), bf16_value(fn), head_of(hw_),
-                                 labels, mask, cfg, ein)
-                return s / jnp.maximum(c, 1.0)
-            return jax.value_and_grad(f, argnums=(0, 1, 2))(x, final_norm, hw)
-
-        @jax.jit
-        def embed(table, ids):
-            return rows(jnp.take(bf16_value(table), ids, axis=0))
-
-        @jax.jit
-        def embed_grad(table, ids, dx):
-            return jnp.zeros(table.shape, F32).at[ids].add(dx)
+        grad = layerwise(family.reference_model(cfg, peft), specs,
+                         frozenset(trainable), _einsum(precision), mesh, row)
 
         @jax.jit
         def sq(g):
@@ -386,32 +401,8 @@ def train(cfg: dict, job: dict, mix: dict, seed: int, steps: int, devices,
             b = batches[t - 1]
             ids, labels, mask = (jax.device_put(b[k], row)
                                  for k in ("ids", "labels", "mask"))
-            train_stack = {p: master[p] for p in blocks if p in moments}
-            frozen_stack = {p: master[p] for p in blocks if p not in moments}
-            xs = [embed(master["embed"], ids)]
-            for l in range(L):
-                xs.append(fwd_layer(xs[-1], {**train_stack, **frozen_stack},
-                                    jnp.int32(l)))
-            loss, (dx, g_fn, g_head) = top(xs[-1], master["final_norm"],
-                                           master[head_key], labels, mask)
+            loss, grads = grad(master, ids, labels, mask)
             losses.append(float(loss))
-            xs.pop()
-            grads = {p: jnp.zeros_like(v) for p, v in train_stack.items()}
-            if "final_norm" in moments:
-                grads["final_norm"] = g_fn
-            for l in reversed(range(L)):
-                dx, g_l = bwd_layer(xs.pop(), train_stack, frozen_stack,
-                                    jnp.int32(l), dx)
-                for p in train_stack:
-                    grads[p] = put(grads[p], g_l[p], jnp.int32(l))
-                del g_l
-            if "embed" in moments:
-                g_e = embed_grad(master["embed"], ids, dx)
-                # tied: the head's gradient is already the table's
-                grads["embed"] = g_e + g_head if tied else g_e
-                if not tied:
-                    grads["head"] = g_head
-            del dx, g_head, g_fn
             norms = {p: float(sq(g)) for p, g in grads.items()}
             total = math.sqrt(sum(norms.values()))
             scale = min(1.0, opt["grad_clip"] / max(total, 1e-12))

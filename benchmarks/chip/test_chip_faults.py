@@ -34,6 +34,9 @@ def run_cell(harness, root, cell, fault=None, monkeypatch=None):
     ("qwen-smoke-lora", None),
     ("qwen-smoke-lora", "unchanged_state"),
     ("qwen-smoke-lora", "half_batch"),
+    ("qwen-smoke-lora-zero3", None),
+    ("qwen-smoke-lora-zero3", "unchanged_state"),
+    ("qwen-smoke-lora-zero3", "half_batch"),
     ("granite-smoke-full", None),
     ("granite-smoke-full", "half_batch"),
     ("granite-smoke-full", "no_exchange"),

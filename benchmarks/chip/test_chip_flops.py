@@ -4,6 +4,7 @@ import jax.numpy as jnp
 import pytest
 
 from benchmarks.chip import flops
+from benchmarks.chip.families import dense
 
 SMOKE = dict(hidden_size=64, intermediate_size=128, num_hidden_layers=2,
              num_attention_heads=4, num_key_value_heads=2, head_dim=16,
@@ -19,11 +20,11 @@ def test_model_flops_by_hand_full_and_lora():
     head = 64 * 512
     attn = 2 * 4 * (S / 2) * 4 * 16          # layers x 4 x S/2 x heads x hd
     fwd = 2 * (2 * layer + head) + attn
-    assert flops.model_flops_per_token(SMOKE, S, None) == 3 * fwd
+    assert dense.model_flops_per_token(SMOKE, S, None) == 3 * fwd
     # adapters per layer: rank 8 x (in + out) for q, k, v, o
     adapters = 2 * 8 * ((64 + 64) + (64 + 32) + (64 + 32) + (64 + 64))
     fwd_lora = fwd + 2 * adapters
-    assert flops.model_flops_per_token(SMOKE, S, LORA) == (
+    assert dense.model_flops_per_token(SMOKE, S, LORA) == (
         2 * fwd_lora + 2 * adapters)
 
 
@@ -92,3 +93,17 @@ def test_peaks_of_the_chip_and_unknown_kind_raises():
     assert "cloud.google.com" in p["source"]
     with pytest.raises(KeyError, match="no peaks"):
         flops.peaks("cpu")
+
+
+@pytest.mark.parametrize("cell,fpt", [
+    ("qwen2.5-3b-lora-fcdp-4k", 13_572_866_048),
+    ("qwen2.5-3b-lora-zero3-4k", 13_572_866_048),
+    ("granite-3-8b-full-fcdp-4x4k", 11_576_352_768),
+])
+def test_model_flops_of_the_cells(cell, fpt):
+    """mfu's FLOPs per token of each cell, by its configuration's family."""
+    from benchmarks.chip import harness
+    c = harness.load_cell(cell)
+    got = c.family.model_flops_per_token(c.config, int(c.mix["seq_len"]),
+                                         c.peft)
+    assert got == fpt

@@ -28,13 +28,13 @@ def test_reference_agrees_with_program_and_control_does_not(
     prog = correct.Readings(losses, g1, harness.change_norms(st, c, SEED))
     harness.free_state(st)
 
-    ref = reference.train(c.config, c.job, c.mix, SEED, 3, devices)
+    ref = reference.train(c.family, c.config, c.job, c.mix, SEED, 3, devices)
     assert set(ref.change_norms) == set(g1)
     got = correct.numbers(prog, ref)
     for name, limit in SMOKE_LIMITS.items():
         assert got[name] <= limit, (name, got)
 
-    ctl = reference.train(c.config, c.job, c.mix, SEED, 3, devices,
+    ctl = reference.train(c.family, c.config, c.job, c.mix, SEED, 3, devices,
                           precision="fp8")
     control = correct.numbers(
         correct.Readings(ctl.losses, ctl.grad_norms[0], ctl.change_norms), ref)
@@ -49,7 +49,7 @@ def test_reference_draws_the_programs_weights(smoke_root, cpu_run):
     harness = cpu_run
     c = harness.load_cell("qwen-smoke-lora", smoke_root)
     st = harness.build(c, SEED, jax.devices()[:1])
-    specs = reference.param_specs(c.config, c.peft)
+    specs = c.family.param_specs(c.config, c.peft)
     keys = reference.leaf_keys(SEED, len(specs))
     b = st.bundle
     labels = [d.label for d in b.def_leaves]

@@ -368,7 +368,7 @@ def test_granite_cell_loads_on_four_chips():
                 ["configs"] if c["name"] == cell.config_name)
     assert set(conf["reduced"]) == set(cell.config["reduced"])
     assert set(conf["reduced"]) <= set(cell.config)
-    m = harness.model_config(cell.config_name, cell.config)
+    m = cell.family.model_config(cell.config_name, cell.config)
     assert (m.d_model, m.num_heads, m.num_kv_heads, m.head_dim, m.d_ff,
             m.vocab_size, m.num_layers) == (4096, 32, 8, 128, 12800, 49155, 8)
     assert m.tie_embeddings and not m.qkv_bias
